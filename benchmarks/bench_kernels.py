@@ -4,8 +4,7 @@ oracles.
 Default mode times the Pallas kernels in interpret mode (CPU wall-time —
 a correctness-adjacent smoke number, not a speed claim).  ``--compiled``
 adds real compiled-kernel rows (``interpret=False``); it requires a TPU
-backend and auto-skips with a message anywhere else, so the same command
-line is safe in CPU CI and on hardware.
+backend and exits non-zero anywhere else.
 
 Schema (``reports/benchmarks/bench_kernels.json``): per kernel,
 ``ref_us`` (jitted jnp oracle), ``pallas_interpret_us``, and with
@@ -17,7 +16,6 @@ Schema (``reports/benchmarks/bench_kernels.json``): per kernel,
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 import jax
@@ -43,10 +41,8 @@ def _time(fn, *args, reps=5):
 
 def run(compiled: bool = False):
     if compiled and jax.default_backend() != "tpu":
-        print(f"# --compiled skipped: backend is "
-              f"{jax.default_backend()!r}, compiled Pallas kernels need "
-              f"a TPU", file=sys.stderr)
-        compiled = False
+        raise SystemExit(f"--compiled needs a TPU; JAX found "
+                         f"{jax.default_backend()!r}")
 
     results = {}
     b, s, h, kh, hd = 1, 512, 8, 2, 64
@@ -120,7 +116,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--compiled", action="store_true",
                     help="also time interpret=False Pallas kernels "
-                         "(TPU only; auto-skips elsewhere)")
+                         "(TPU only; fails elsewhere)")
     args = ap.parse_args()
     run(compiled=args.compiled)
 

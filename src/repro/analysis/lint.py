@@ -557,8 +557,9 @@ def _check_ra010(m: Module) -> Iterable[Finding]:
                     "RA010", node,
                     f"jitted kernel wrapper `{node.name}` defaults "
                     f"`interpret={dflt.value!r}`: default it to None and "
-                    f"derive from the backend (`jax.default_backend()`), so "
-                    f"the CPU-interpret guard cannot be skipped by default")
+                    f"derive it from the lowering platform "
+                    f"(`repro.kernels.dispatch.run_kernel`), so the "
+                    f"CPU-interpret guard cannot be skipped by default")
 
 
 # ------------------------------------------------------------------ RA011 ---

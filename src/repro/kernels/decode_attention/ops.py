@@ -1,4 +1,5 @@
-"""jit'd wrapper for the decode-attention Pallas kernel (interpret on CPU)."""
+"""jit'd wrapper for the decode-attention Pallas kernel (compiled on TPU,
+interpret mode elsewhere; see :mod:`repro.kernels.dispatch`)."""
 from __future__ import annotations
 
 import functools
@@ -8,10 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_pallas)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import run_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("blk_k", "interpret"))
@@ -21,18 +19,11 @@ def decode_attention(q, k, v, lengths, *, blk_k=256, interpret=None):
     Rows with ``length == 0`` return zeros (empty online softmax): the
     serving path hands the kernel the full fixed-slot batch, and inactive
     slots carry length 0 — their output must be finite (it is discarded),
-    never NaN."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    never NaN.  ``T`` need not be a multiple of ``blk_k``."""
     b, h, hd = q.shape
-    t, kh = k.shape[1], k.shape[2]
-    g = h // kh
-    blk_k = min(blk_k, max(8, t))
-    pad = (-t) % blk_k
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    qg = q.reshape(b, kh, g, hd)
-    out = decode_attention_pallas(qg, k, v, lengths.astype(jnp.int32),
-                                  blk_k=blk_k, interpret=interpret)
+    kh = k.shape[2]
+    qg = q.reshape(b, kh, h // kh, hd)
+    out = run_kernel(decode_attention_pallas, qg, k, v,
+                     lengths.astype(jnp.int32), blk_k=blk_k,
+                     interpret=interpret)
     return out.reshape(b, h, hd)

@@ -1,7 +1,8 @@
 """Causal GQA flash attention as a Pallas TPU kernel.
 
 TPU adaptation of the flash-attention tiling (DESIGN.md §3): the grid is
-(batch, q_head, q_block, kv_block) with the KV axis innermost; online-softmax
+(batch, q_block, kv_block) with the KV axis innermost, and each step walks
+every query head of its tiles in a static loop; per-head online-softmax
 statistics (m, l) and the fp32 output accumulator live in VMEM scratch and
 carry across the kv_block grid steps (TPU grids execute sequentially per
 core, so scratch carries replace the CUDA warp-level loop).  Q/K/V tiles
@@ -25,11 +26,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             blk_q: int, blk_k: int, causal: bool, sm_scale: float):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -39,8 +40,8 @@ def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     q_start = qi * blk_q
     k_start = ki * blk_k
-    kv_len = kvlen_ref[0]
-    q_len = kvlen_ref[1]
+    kv_len = lens_ref[0]
+    q_len = lens_ref[1]
     # causal diagonal offset: with an offset KV cache (kv_len > q_len) the
     # first query row may already attend to kv_len - q_len leading keys
     off = kv_len - q_len
@@ -50,31 +51,35 @@ def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (blk_k, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-        s = jnp.where(cols < kv_len, s, NEG_INF)           # padded keys inert
+        keep = cols < kv_len                               # padded keys inert
         if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            s = jnp.where(cols <= rows + off, s, NEG_INF)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+            rows = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (blk_q, blk_k), 0)
+            keep = jnp.logical_and(keep, cols <= rows + off)
+        g = q_ref.shape[2] // k_ref.shape[2]
+        for h in range(q_ref.shape[2]):
+            q = q_ref[0, :, h, :].astype(jnp.float32)      # (blk_q, hd)
+            k = k_ref[0, :, h // g, :].astype(jnp.float32)  # (blk_k, hd)
+            v = v_ref[0, :, h // g, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[h]                              # (blk_q, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        for h in range(q_ref.shape[2]):
+            l = jnp.maximum(l_scr[h], 1e-30)
+            o_ref[0, :, h, :] = (acc_scr[h] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, blk_q=128, blk_k=128,
@@ -83,14 +88,14 @@ def flash_attention_pallas(q, k, v, *, causal=True, blk_q=128, blk_k=128,
     kv_len masks keys at positions ≥ kv_len (right padding).  q_len is the
     true (unpadded) query length: with kv_len > q_len the causal diagonal
     is shifted so the last query row attends to all kv_len keys (offset
-    cache, matching the reference oracle)."""
+    cache, matching the reference oracle).  Both reach the kernel as a
+    scalar-prefetch operand.  Each grid step takes every head, so the
+    blocks' last two dims equal the arrays' ``(H, hd)`` / ``(K, hd)``."""
     b, s, h, hd = q.shape
     t, kh = k.shape[1], k.shape[2]
-    g = h // kh
     blk_q = min(blk_q, s)
     blk_k = min(blk_k, t)
     assert s % blk_q == 0 and t % blk_k == 0
-    grid = (b, h, s // blk_q, t // blk_k)
     sm_scale = 1.0 / np.sqrt(hd)
 
     kernel = functools.partial(_kernel, blk_q=blk_q, blk_k=blk_k,
@@ -99,24 +104,26 @@ def flash_attention_pallas(q, k, v, *, causal=True, blk_q=128, blk_k=128,
         kv_len = t
     if q_len is None:
         q_len = kv_len          # square case: diagonal ends at the corner
-    kv_len_arr = jnp.asarray([kv_len, q_len], jnp.int32)
+    lens = jnp.asarray([kv_len, q_len], jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // blk_q, t // blk_k),
+        in_specs=[
+            pl.BlockSpec((1, blk_q, h, hd), lambda b_, q_, k_, n: (b_, q_, 0, 0)),
+            pl.BlockSpec((1, blk_k, kh, hd), lambda b_, q_, k_, n: (b_, k_, 0, 0)),
+            pl.BlockSpec((1, blk_k, kh, hd), lambda b_, q_, k_, n: (b_, k_, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, blk_q, h, hd),
+                               lambda b_, q_, k_, n: (b_, q_, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, blk_q, 1), jnp.float32),
+            pltpu.VMEM((h, blk_q, 1), jnp.float32),
+            pltpu.VMEM((h, blk_q, hd), jnp.float32),
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda b_, h_, q_, k_: (0,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, blk_q, 1, hd), lambda b_, h_, q_, k_: (b_, q_, h_, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b_, h_, q_, k_: (b_, k_, h_ // g, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd), lambda b_, h_, q_, k_: (b_, k_, h_ // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, hd),
-                               lambda b_, h_, q_, k_: (b_, q_, h_, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(kv_len_arr, q, k, v)
+    )(lens, q, k, v)

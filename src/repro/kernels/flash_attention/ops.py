@@ -2,9 +2,9 @@
 
 On TPU the kernel runs compiled with MXU-aligned tiles; elsewhere it runs in
 ``interpret=True`` mode (the kernel body executed by XLA:CPU) so correctness
-is testable in this container.  Non-multiple sequence lengths are padded on
-the right (causal masking keeps padded keys inert; padded queries are
-sliced off).
+is testable without a chip (see :mod:`repro.kernels.dispatch`).
+Non-multiple sequence lengths are padded on the right (causal masking keeps
+padded keys inert; padded queries are sliced off).
 """
 from __future__ import annotations
 
@@ -13,19 +13,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import run_kernel
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal=True, blk_q=128, blk_k=128,
                     interpret=None):
-    if interpret is None:
-        interpret = not _on_tpu()
     b, s, h, hd = q.shape
     t = k.shape[1]
     blk_q = min(blk_q, max(8, s))
@@ -37,7 +32,7 @@ def flash_attention(q, k, v, *, causal=True, blk_q=128, blk_k=128,
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    out = flash_attention_pallas(q, k, v, causal=causal, blk_q=blk_q,
-                                 blk_k=blk_k, interpret=interpret, kv_len=t,
-                                 q_len=s)
+    out = run_kernel(flash_attention_pallas, q, k, v, causal=causal,
+                     blk_q=blk_q, blk_k=blk_k, kv_len=t, q_len=s,
+                     interpret=interpret)
     return out[:, :s]

@@ -1,4 +1,5 @@
-"""jit'd wrapper for the paged-attention Pallas kernel (interpret on CPU)."""
+"""jit'd wrapper for the paged-attention Pallas kernel (compiled on TPU,
+interpret mode elsewhere; see :mod:`repro.kernels.dispatch`)."""
 from __future__ import annotations
 
 import functools
@@ -9,10 +10,7 @@ import jax.numpy as jnp
 from repro.kernels.paged_attention.paged_attention import (
     paged_attention_pallas)
 from repro.kernels.paged_attention.ref import gather_pages  # noqa: F401
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import run_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -26,8 +24,6 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     ``length``); lengths are clamped to the table's addressable window.
     Rows with ``length == 0`` return zeros — inactive serving slots must
     come back finite, never NaN."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, h, hd = q.shape
     kh = k_pool.shape[2]
     g = h // kh
@@ -35,6 +31,6 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     table = jnp.clip(page_table.astype(jnp.int32), 0, k_pool.shape[0] - 1)
     lengths = jnp.minimum(lengths.astype(jnp.int32),
                           table.shape[1] * k_pool.shape[1])
-    out = paged_attention_pallas(qg, k_pool, v_pool, table, lengths,
-                                 interpret=interpret)
+    out = run_kernel(paged_attention_pallas, qg, k_pool, v_pool, table,
+                     lengths, interpret=interpret)
     return out.reshape(b, h, hd)

@@ -78,6 +78,7 @@ class Model:
         self.period, self.descs = layer_layout(cfg)
         self.n_periods = cfg.num_layers // self.period
         self.use_flash = False  # engines may switch on Pallas attention
+        self._init_jit = jax.jit(self._init, static_argnums=1)
 
     # ------------------------------------------------------------- init ----
 
@@ -108,6 +109,12 @@ class Model:
                 for i, d in enumerate(descs)}
 
     def init(self, rng, dtype=jnp.float32):
+        """Seeded random parameters in ``dtype``, drawn under ``jit``: each
+        weight is sampled and cast inside one program on the default
+        device, so a bf16 model never holds a float32 copy of its stack."""
+        return self._init_jit(rng, dtype)
+
+    def _init(self, rng, dtype):
         cfg = self.cfg
         r = jax.random.split(rng, 6)
         params = {

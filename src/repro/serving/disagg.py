@@ -1,12 +1,12 @@
-"""End-to-end disaggregated cluster on real (reduced) models — the
+"""End-to-end disaggregated cluster on real models — the
 **engine backend** of the shared :class:`~repro.serving.control_plane.ControlPlane`.
 
 One prefill engine + N decode engines, glued by the same control plane the
 analytic simulator runs on: Smart Router (Eq. 1/2) with KvIndexer overlap,
 adaptive controller (saturation detector + Table 2 regime params), PoA
-tracker, and per-request metrics.  This is the production pattern at test
-scale: the same code path drives TPU submeshes when the engines are built
-on disjoint device sets.
+tracker, and per-request metrics.  Every engine sits on the default device:
+on one TPU chip prefill and decode share it, and there is no path yet that
+places engines on separate chips.
 
 What makes this backend *real* rather than modeled:
 
@@ -16,9 +16,9 @@ What makes this backend *real* rather than modeled:
   skips actual jitted compute (cold requests pay the full pass);
 * the prefill→decode ``transfer()`` hop is charged per **non-resident**
   block on the chosen decode worker (``kv_transfer_per_block`` seconds per
-  block, added to the recorded TTFT/latency): on CPU the hop is an
-  in-process copy, and the per-block charge reintroduces the KV-movement
-  cost NetKV shows dominates decode-instance selection;
+  block, added to the recorded TTFT/latency): the hop is an in-process
+  copy on one device, and the per-block charge reintroduces the
+  KV-movement cost NetKV shows dominates decode-instance selection;
 * per-token inter-token latencies are observed into the metrics registry,
   so ``violation_rates``' ITL side and the Planner's v_ITL signal are
   non-degenerate on real engines.

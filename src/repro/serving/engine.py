@@ -16,16 +16,16 @@ slots are released **inside** :meth:`DecodeEngine.step` — the returned-slot
 contract: a ``done=True`` tuple means the slot is already free and
 re-admittable in the same tick.  The engine also tracks which KV blocks are
 resident (admitted and not yet evicted by the bounded LRU), so the
-prefill→decode ``transfer()`` hop can be charged per *non-resident* block —
-on a real cluster that hop is a cross-mesh ``jax.device_put`` (the NIXL
-analogue); on CPU it degenerates to an in-process copy, so the per-block
-charge is what reintroduces the KV-movement cost the routing game is about.
+prefill→decode ``transfer()`` hop can be charged per *non-resident* block.
+Every engine sits on the default device, so the hop is an in-process copy
+on one device; the per-block charge is what reintroduces the KV-movement
+cost the routing game is about.  A hop between chips does not exist yet.
 """
 from __future__ import annotations
 
 import functools
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
@@ -105,16 +105,41 @@ class PrefillEngine:
         for every resume point inside them."""
         best, donor, key = 0, None, None
         for chain in reversed(self._cache):   # most recent first
-            m = 0
-            for a, b in zip(chain, hashes):
-                if a != b:
-                    break
-                m += 1
+            m = _shared_depth(chain, hashes)
             if m > best:
                 best, donor, key = m, self._cache[chain], chain
         if key is not None:
             self._cache.move_to_end(key)
         return best, donor
+
+    def _resume_start(self, n: int, m: int) -> int:
+        """Resume point of an ``n``-token prompt whose first ``m`` blocks
+        are cached.  At least one suffix token is kept so the pass emits
+        this prompt's logits; the donor matched ``m`` full blocks, which
+        covers every position below any start ≤ m·block_size (including a
+        non-boundary start inside the donor's last matched block).  0
+        means a cold pass."""
+        return max(0, min(m * self.block_size, n - 1))
+
+    def resume_suffixes(self, prompts: Sequence[Sequence[int]]) -> List[int]:
+        """Suffix lengths a prefix-cache resume can run over a stream of
+        ``prompts`` — the warmup pre-compile set.  A prompt resumes from
+        the full-block prefix it shares with a cached earlier prompt (an
+        identical one included), so only the pairwise shared depths of
+        the stream's distinct prompts can occur."""
+        if not (self.model.supports_prefill_resume and self.cache_entries > 0):
+            return []
+        counts = Counter(tuple(p) for p in prompts)
+        chains = {p: block_hashes(p, self.block_size) for p in counts}
+        suffixes = set()
+        for a, ha in chains.items():
+            for b, hb in chains.items():
+                if a == b and counts[a] < 2:
+                    continue
+                start = self._resume_start(len(a), _shared_depth(ha, hb))
+                if start > 0:
+                    suffixes.add(len(a) - start)
+        return sorted(suffixes)
 
     def _store(self, hashes: Sequence[int], caches) -> None:
         if not hashes or self.cache_entries <= 0:
@@ -213,12 +238,8 @@ class PrefillEngine:
         donor = None
         if resumable and hashes:
             m, donor = self._best_match(hashes)
-            # keep ≥1 suffix token so the pass emits this prompt's logits;
-            # the donor matched m full blocks, which covers every position
-            # below any start ≤ m·block_size (including a non-boundary
-            # start inside the donor's last matched block)
-            start = min(m * self.block_size, len(tokens) - 1)
-            if start <= 0:
+            start = self._resume_start(len(tokens), m)
+            if start == 0:
                 donor = None
         t0 = time.perf_counter()
         if start > 0:
@@ -297,9 +318,9 @@ class PrefillEngine:
             start, donor = 0, None
             if resumable and hashes:
                 m, donor = self._best_match(hashes)
-                start = min(m * self.block_size, len(tokens) - 1)
-                if start <= 0:
-                    start, donor = 0, None
+                start = self._resume_start(len(tokens), m)
+                if start == 0:
+                    donor = None
             if donor is not None:
                 resume.setdefault((start, len(tokens)), []).append(
                     (i, tokens, hashes, donor))
@@ -385,6 +406,16 @@ class PrefillEngine:
             if hashes:
                 self._store(hashes, jax.tree.map(
                     lambda a, r=r: a[:, r:r + 1], caches))
+
+
+def _shared_depth(a: Sequence[int], b: Sequence[int]) -> int:
+    """Leading positions on which two block-hash chains agree."""
+    m = 0
+    for x, y in zip(a, b):
+        if x != y:
+            break
+        m += 1
+    return m
 
 
 @dataclass
@@ -524,17 +555,22 @@ class DecodeEngine:
             w *= 2
         return min(w, self.max_pages_per_slot)
 
-    def width_ladder(self, total_tokens: Optional[int] = None) -> List[int]:
+    def width_ladder(self, total_tokens: Optional[int] = None,
+                     min_prompt: int = 0) -> List[int]:
         """Every page-table width a run can emit, widest bounded by
         ``total_tokens`` (prompt + generated; None = the ``max_len`` worst
-        case) — the warmup pre-compile set for the decode step."""
+        case) — the warmup pre-compile set for the decode step.  The table
+        only widens, and admission already maps a prompt's pages, so widths
+        below the shortest prompt's (``min_prompt`` tokens) never step."""
         top = self.max_pages_per_slot if total_tokens is None else \
             self._table_width(self.allocator.pages_for(
                 min(total_tokens, self.max_len)))
-        ladder, w = [], 1
+        w = self._table_width(self.pages_for_prompt(min_prompt)) \
+            if min_prompt > 0 else 1
+        ladder = []
         while w < top:
             ladder.append(w)
-            w *= 2
+            w = self._table_width(2 * w)
         ladder.append(top)
         return ladder
 
@@ -623,7 +659,7 @@ class DecodeEngine:
                                       jnp.asarray(pages, jnp.int32))
         else:
             self.caches = _insert_cache(self.caches, prefill_caches, slot,
-                                        self.model, src_row=src_row)
+                                        src_row)
         s = self.slots[slot]
         s.active = True
         s.request_id = request_id
@@ -652,16 +688,17 @@ class DecodeEngine:
 
         On a paged engine, ``table_widths`` lists the page-table widths to
         pre-compile (each width is its own decode-step shape — the
-        page-growth recompile points; see :meth:`width_ladder`).  The live
-        table keeps its current width; pre-compiled shapes are hit when
-        growth widens it later."""
+        page-growth recompile points; see :meth:`width_ladder`); None
+        compiles the live table's current width.  The live table keeps its
+        current width; pre-compiled shapes are hit when admission or growth
+        widens it later."""
         lengths = jnp.zeros((self.num_slots,), jnp.int32)
         if not self.paged:
             _, self.caches = self._decode(self.params, self.caches,
                                           jnp.asarray(self.tokens), lengths)
             return
-        widths = sorted({int(w) for w in (table_widths or ())}
-                        | {self.page_table.shape[1]})
+        widths = sorted({int(w) for w in (table_widths
+                                          or (self.page_table.shape[1],))})
         for w in widths:
             table = jnp.zeros((self.num_slots, w), jnp.int32)
             _, self.caches = self._decode(self.params, self.caches,
@@ -752,14 +789,13 @@ def adopt_prefill_pages(pool, row_bundle, page_ids, *, block: int):
     return jax.tree.map(leaf, pool, row_bundle)
 
 
-def _insert_cache(dst, src, slot: int, model: Model, src_row: int = 0):
+@functools.partial(jax.jit, donate_argnums=0)
+def _insert_cache(dst, src, slot, src_row):
     """Write row ``src_row`` of a prefill cache bundle into decode slot
     ``slot`` (batched prefill emits multi-row bundles; the sequential path
-    keeps row 0).
-
-    Cross-mesh in production: each leaf is device_put to the decode mesh's
-    sharding before insertion.
-    """
+    keeps row 0).  ``dst`` is donated, so the decoder cache is updated in
+    place, and ``slot``/``src_row`` are traced: one compile per bundle
+    shape serves every slot and row."""
     def leaf(d, s):
         # d: (P, B, ...); s: (P, W, ...) — prefill cache may have a shorter
         # sequence axis than the decode cache; pad on the right.
@@ -768,5 +804,8 @@ def _insert_cache(dst, src, slot: int, model: Model, src_row: int = 0):
             for ds, ss in zip(d.shape[2:], s.shape[2:]):
                 pads.append((0, ds - ss))
             s = jnp.pad(s, pads)
-        return d.at[:, slot].set(s[:, src_row].astype(d.dtype))
+        row = jax.lax.dynamic_index_in_dim(s, src_row, axis=1,
+                                           keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(d, row.astype(d.dtype),
+                                                   slot, axis=1)
     return jax.tree.map(leaf, dst, src)

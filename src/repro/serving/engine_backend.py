@@ -1,9 +1,10 @@
 """Engine backend of the scenario registry.
 
 Materializes a named scenario's request stream onto the real-JAX
-:class:`~repro.serving.disagg.DisaggregatedCluster` (reduced CPU-testable
-models), so every registered scenario can run against actual jitted
-compute instead of the analytic latency model::
+:class:`~repro.serving.disagg.DisaggregatedCluster` (the reduced
+CPU-testable model by default; ``model=``/``params=`` inject any other,
+such as a published-width one on a TPU), so every registered scenario can
+run against actual jitted compute instead of the analytic latency model::
 
     from repro.serving.scenarios import build_backend
 
@@ -171,17 +172,15 @@ class EngineScenarioRunner:
 
     # ---------------------------------------------------------------- run ---
 
-    def _warmup(self) -> None:
+    def warmup(self) -> None:
         """Compile every jitted/XLA shape this run will hit, outside the
         measured path (compile walls would otherwise read as multi-second
         TTFTs and drive the saturation detector across θ1)."""
-        block = self.cluster.prefill.block_size
+        import jax
+        import jax.numpy as jnp
         lengths = sorted(set(len(s.tokens) for s in self.specs))
-        suffixes = set()
-        for n in lengths:
-            for m in range(1, n // block + 1):
-                start = min(m * block, n - 1)
-                suffixes.add(n - start)
+        suffixes = self.cluster.prefill.resume_suffixes(
+            [s.tokens for s in self.specs])
         # serialized runs only ever issue width-1 batched passes; flood
         # runs can fill a whole tick's admissions, so pre-compile every
         # power-of-two width the bucketing can emit
@@ -190,8 +189,7 @@ class EngineScenarioRunner:
         while self.cluster.batch_prefill and not self.serialize \
                 and widths[-1] * 2 <= cap:
             widths.append(widths[-1] * 2)
-        self.cluster.prefill.warmup(lengths, sorted(suffixes),
-                                    batch_sizes=widths)
+        self.cluster.prefill.warmup(lengths, suffixes, batch_sizes=widths)
         # the admit path (cache insertion scatter) and the decode step
         # compile on first use too; run one dummy admit→step→auto-release
         # per decoder (empty hash list: no residency/transfer pollution)
@@ -204,7 +202,8 @@ class EngineScenarioRunner:
                 # mid-run block-boundary crossing never pays a compile wall
                 span = max((len(s.tokens) + s.max_new + 1
                             for s in self.specs), default=lengths[-1] + 2)
-                dec.warmup(table_widths=dec.width_ladder(span))
+                dec.warmup(table_widths=dec.width_ladder(
+                    span, min_prompt=lengths[0]))
                 # the adopt scatter compiles per mapped-page count: one
                 # dummy admit+release per distinct count the prompts map
                 reps = {}
@@ -219,6 +218,15 @@ class EngineScenarioRunner:
                     dec.release(0)
             else:
                 dec.warmup()
+                # the slot insert compiles once per prefill bundle width
+                for w in widths[1:]:
+                    wide = jax.tree.map(
+                        lambda a, w=w: jnp.concatenate([a] * w, axis=1),
+                        caches)
+                    dec.admit(0, "__warmup__", wide, 0,
+                              prompt_len=lengths[-1], max_new=1, hashes=(),
+                              src_row=w - 1)
+                    dec.release(0)
             dec.admit(0, "__warmup__", caches, 0,
                       prompt_len=lengths[-1], max_new=1, hashes=())
             dec.step()                      # done=True → slot auto-released
@@ -232,7 +240,7 @@ class EngineScenarioRunner:
 
     def run(self) -> EngineRunResult:
         if self.warmup_enabled:
-            self._warmup()
+            self.warmup()
         cl = self.cluster
         for i, spec in enumerate(self.specs):
             cl.submit(ServeRequest(f"r{i}", list(spec.tokens),

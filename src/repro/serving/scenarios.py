@@ -155,8 +155,8 @@ def build_backend(name: str, backend: str = "analytic", seed: int = 0,
     ``backend="analytic"`` returns the event-driven :class:`Simulator`
     (identical to :func:`build_simulator`); ``backend="engine"`` returns an
     :class:`~repro.serving.engine_backend.EngineScenarioRunner` that drives
-    the scenario's request stream through real jitted-JAX engines on a
-    reduced CPU-testable model.  Both route through the shared
+    the scenario's request stream through real jitted-JAX engines (a
+    reduced CPU-testable model unless ``model=``/``params=`` are given).  Both route through the shared
     :class:`~repro.serving.control_plane.ControlPlane`."""
     if backend == "analytic":
         return build_simulator(name, seed=seed, **overrides)
