@@ -109,4 +109,5 @@ def decode_attention_pallas(q, k, v, lengths, *, blk_k=256, interpret=False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, q, k, v)

@@ -126,4 +126,5 @@ def flash_attention_pallas(q, k, v, *, causal=True, blk_q=128, blk_k=128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(lens, q, k, v)

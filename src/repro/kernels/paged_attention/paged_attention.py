@@ -103,4 +103,5 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, q, k_pool, v_pool)

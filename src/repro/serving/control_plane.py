@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import math
 import random
-import time
 from collections import deque
 from dataclasses import replace
 from typing import (Deque, Dict, List, Mapping, NamedTuple, Optional,
@@ -60,6 +59,7 @@ from repro.core.poa import PoATracker
 from repro.core.router import (KvPushRouter, KvRouterConfig, PowerOfTwoRouter,
                                RandomRouter, RoundRobinRouter)
 from repro.core.saturation import DetectorConfig, Regime, SaturationDetector
+from repro.core.tracing import Tracer
 from repro.serving.fabric import transfer_block_count
 
 
@@ -358,7 +358,8 @@ class ControlPlane:
                  decision_log_maxlen: Optional[int] = None,
                  fabric=None,                   # repro.serving.fabric.Fabric
                  network_aware: bool = False,
-                 sanitize: Optional[bool] = None):
+                 sanitize: Optional[bool] = None,
+                 tracer: Optional[Tracer] = None):
         # Fourth game: an attached Fabric prices P→D transfers on shared
         # links; network_aware additionally folds each candidate's quoted
         # transfer time into the routing cost (requires the kv policy —
@@ -397,6 +398,12 @@ class ControlPlane:
         self.dual = DualFrontend()
         self.regime_params = dict(regime_params or REGIME_PARAMS)
         self.metrics = MetricsRegistry()
+        # host spans of route (off unless the tracer is enabled); the
+        # engine backend hands its own shared tracer in
+        self.tracer = tracer if tracer is not None else Tracer()
+        self._route_calls = self.metrics.counter(
+            "route_calls_total", "routing calls, retries of blocked "
+            "requests included")
         self.switch_time: Optional[float] = None
 
         # Game 1: the Planner joins the control plane when configured.
@@ -504,15 +511,21 @@ class ControlPlane:
               ) -> Tuple[int, float, List[float], List[int]]:
         """Engine-path routing: :meth:`select_worker` plus the Algorithm 1
         Prometheus exports (game_poa, game_saturation_state,
-        game_router_temperature, game_overlap_weight, game_routing_cost)."""
-        t0 = time.perf_counter()
-        worker, overlap, overlaps, ids = self.select_worker(
-            tokens, hashes=hashes, now=now, live_ids=live_ids, rid=rid,
-            record=record)
-        dt = time.perf_counter() - t0
-        export_game_metrics(self.metrics, regime=self.detector.regime,
-                            config=self._last_config, decision_s=dt,
-                            now=now, poa_tracker=self.poa)
+        game_router_temperature, game_overlap_weight, game_routing_cost).
+        The ``route.select`` span is the decision time ``game_routing_cost``
+        observes; ``route.export`` holds the PoA solve."""
+        self._route_calls.inc()
+        tr = self.tracer
+        with tr.span("route", rid=rid):
+            with tr.timed("route.select") as sel:
+                worker, overlap, overlaps, ids = self.select_worker(
+                    tokens, hashes=hashes, now=now, live_ids=live_ids,
+                    rid=rid, record=record)
+            with tr.span("route.export"):
+                export_game_metrics(self.metrics, regime=self.detector.regime,
+                                    config=self._last_config,
+                                    decision_s=sel.seconds, now=now,
+                                    poa_tracker=self.poa)
         return worker, overlap, overlaps, ids
 
     # --------------------------------------------------------- telemetry ----

@@ -35,6 +35,7 @@ from repro.core.poa import CompletedRequest
 from repro.core.radix import block_hashes
 from repro.core.router import KvRouterConfig
 from repro.core.saturation import DetectorConfig
+from repro.core.tracing import Tracer
 from repro.models.model import Model
 from repro.serving.control_plane import ControlPlane
 from repro.serving.engine import DecodeEngine, PrefillEngine
@@ -62,6 +63,10 @@ class ServeRequest:
     # queueing, and the uncongested (OPT) transfer time
     transfer_wait: float = 0.0
     transfer_floor: float = 0.0
+    # placement: when the request took its decode slot (cluster clock; the
+    # end of its ``queue`` span), and the routing calls it took to get there
+    placed_t: float = 0.0
+    route_calls: int = 0
 
     @property
     def ttft(self) -> float:
@@ -102,6 +107,9 @@ class DisaggregatedCluster:
                  sanitize: Optional[bool] = None):
         self.model = model
         self.batch_prefill = batch_prefill
+        # one tracer for the cluster, its control plane and its engines
+        # (off unless enabled; an injected control plane brings its own)
+        self.tracer = control.tracer if control is not None else Tracer()
         # Fourth game: decode NICs 0..N-1 plus one prefill node at wid=N
         # (the engine runs a single prefill engine); transfers serialize on
         # the shared links instead of the flat per-block charge.  Only used
@@ -110,14 +118,16 @@ class DisaggregatedCluster:
                        if fabric is not None else None)
         self.prefill = PrefillEngine(model, params, max_len,
                                      cache_entries=prefill_cache_entries,
-                                     max_batch=max_prefill_batch)
+                                     max_batch=max_prefill_batch,
+                                     tracer=self.tracer)
         # num_pages sizes each paged decoder's KV page pool (None = the
         # dense worst case, where the page gate never binds); dense impls
         # ignore it.
         self.decoders = [DecodeEngine(model, params, slots_per_worker,
                                       max_len, worker_id=i,
                                       decode_impl=decode_impl,
-                                      num_pages=num_pages)
+                                      num_pages=num_pages,
+                                      tracer=self.tracer)
                          for i in range(num_decode)]
         # Replica-view sync cadence on the engine backend: the scheduler
         # tick IS the event clock, so views refresh every
@@ -140,7 +150,8 @@ class DisaggregatedCluster:
                 log_decisions=True,
                 fabric=self.fabric,
                 network_aware=network_aware,
-                sanitize=False)   # the cluster attaches its own, richer one
+                sanitize=False,   # the cluster attaches its own, richer one
+                tracer=self.tracer)
             if replicas is None:
                 self.control = ControlPlane(num_decode, **plane_kw)
             else:
@@ -153,6 +164,12 @@ class DisaggregatedCluster:
         self.router = self.control.router
         self.poa = self.control.poa
         self.metrics = self.control.metrics
+        self._ticks_total = self.metrics.counter(
+            "ticks_total", "scheduler ticks")
+        self._placements = self.metrics.counter(
+            "placements_total", "requests given a decode slot")
+        self._hops = self.metrics.counter(
+            "hops_total", "prefill caches moved into a decode slot")
         self.kv_transfer_per_block = kv_transfer_per_block
         self.pending: List[ServeRequest] = []
         self.running: Dict[str, Tuple[ServeRequest, int, int]] = {}
@@ -182,10 +199,12 @@ class DisaggregatedCluster:
         return time.monotonic() - self._t0
 
     def submit(self, req: ServeRequest):
-        req.submit_t = self._now()
-        if not req.hashes:
-            req.hashes = tuple(block_hashes(req.tokens))
-        self.pending.append(req)
+        with self.tracer.span("submit", rid=req.request_id,
+                              detail=len(req.tokens)):
+            req.submit_t = self._now()
+            if not req.hashes:
+                req.hashes = tuple(block_hashes(req.tokens))
+            self.pending.append(req)
 
     def _try_schedule(self):
         still: List[ServeRequest] = []
@@ -199,6 +218,7 @@ class DisaggregatedCluster:
             # the decision_log must hold one entry per *placement*, not
             # one per abandoned attempt.
             now = self._now()
+            req.route_calls += 1
             worker, overlap, overlaps, _ids = self.control.route(
                 req.tokens, hashes=req.hashes, now=now,
                 rid=req.request_id, record=False)
@@ -218,8 +238,13 @@ class DisaggregatedCluster:
             # bucketed prompt pass below.
             dec.reserve(slot, req.request_id, prompt_len=len(req.tokens),
                         max_new=req.max_new_tokens)
+            req.placed_t = self._now()
+            self._placements.inc()
+            self.tracer.record("queue", self._t0 + req.submit_t,
+                               self._t0 + req.placed_t, rid=req.request_id,
+                               detail=worker)
             self.control.router.on_schedule(worker, req.tokens,
-                                            now=self._now(),
+                                            now=req.placed_t,
                                             hashes=req.hashes)
             req.worker = worker
             req.overlap = overlap
@@ -231,10 +256,12 @@ class DisaggregatedCluster:
         if self.batch_prefill:
             outs = self.prefill.prefill_many(
                 [(req.tokens, req.extras, req.hashes)
-                 for req, _, _ in placed])
+                 for req, _, _ in placed],
+                rids=[req.request_id for req, _, _ in placed])
         else:
             outs = [self.prefill.prefill(req.tokens, req.extras,
-                                         hashes=req.hashes) + (0,)
+                                         hashes=req.hashes,
+                                         rid=req.request_id) + (0,)
                     for req, _, _ in placed]
         for (req, worker, slot), (logits, caches, row) in zip(placed, outs):
             first = int(np.argmax(logits))
@@ -242,6 +269,7 @@ class DisaggregatedCluster:
                 slot, req.request_id, caches, first,
                 prompt_len=len(req.tokens), max_new=req.max_new_tokens,
                 hashes=req.hashes, src_row=row)
+            self._hops.inc()
             req.transfer_blocks = moved
             if self.fabric is not None:
                 # enqueue the sized transmission on the shared links; the
@@ -269,51 +297,72 @@ class DisaggregatedCluster:
     def step(self) -> int:
         """One scheduler tick: admit pending, advance every decode engine.
         Returns number of completed requests this tick."""
-        if self.fabric is not None:
-            # lazy settlement: the engine has no event queue, so landed
-            # transmissions release their link reservations at tick start
-            self.fabric.complete_until(self._now())
-        if self.staleness_ticks > 0:
-            if self._ticks % self.staleness_ticks == 0:
-                self.control.sync_views(self._now())
-            self._ticks += 1
-        self._try_schedule()
-        self.occupancy.append(tuple(d.active_count for d in self.decoders))
-        if any(d.paged for d in self.decoders):
-            self.pool_utilization.append(
-                tuple(d.pool_utilization() for d in self.decoders))
+        self._ticks_total.inc()
+        tr = self.tracer
+        with tr.span("tick"):
+            if self.fabric is not None:
+                # lazy settlement: the engine has no event queue, so landed
+                # transmissions release their link reservations at tick
+                # start
+                self.fabric.complete_until(self._now())
+            if self.staleness_ticks > 0:
+                if self._ticks % self.staleness_ticks == 0:
+                    self.control.sync_views(self._now())
+                self._ticks += 1
+            with tr.span("schedule", detail=len(self.pending)):
+                self._try_schedule()
+            self.occupancy.append(tuple(d.active_count
+                                        for d in self.decoders))
+            if any(d.paged for d in self.decoders):
+                self.pool_utilization.append(
+                    tuple(d.pool_utilization() for d in self.decoders))
+            completed = 0
+            for dec in self.decoders:
+                out = dec.step()
+                if not out:
+                    continue
+                with tr.span("complete") as sp:
+                    sp.detail = n = self._complete(out)
+                completed += n
+            # controller telemetry poll (every tick at test scale)
+            with tr.span("telemetry"):
+                ttft_p99 = self.metrics.histogram(
+                    "ttft", window_s=300.0).p99(self._now())
+                self.control.observe(ttft_p99, self._now())
+        return completed
+
+    def _complete(self, out: List[Tuple[str, int, bool]]) -> int:
+        """Token and completion bookkeeping of one decoder's step: the
+        per-token ITL, and on completion the router, TTFT and PoA records.
+        Returns the requests completed."""
         completed = 0
-        for dec in self.decoders:
-            for rid, tok, done in dec.step():
-                req, worker, _slot = self.running[rid]
-                now = self._now()
-                req.output.append(tok)
-                # per-token ITL: every decode step contributes a sample, so
-                # the ITL histogram (and the Planner's v_ITL) is live on
-                # the engine path, not just TTFT
-                self.metrics.histogram("itl", window_s=300.0).observe(
-                    now - req.last_token_t, now)
-                req.last_token_t = now
-                if done:
-                    # slot already released inside dec.step() (returned-slot
-                    # contract: done=True means re-admittable this tick)
-                    req.finish_t = now
-                    del self.running[rid]
-                    self.done.append(req)
-                    self.control.router.on_complete(worker, req.tokens)
-                    self.metrics.histogram("ttft", window_s=300.0).observe(
-                        req.charged_ttft, now)
-                    self.poa.record(CompletedRequest(
-                        request_id=rid, worker=worker,
-                        latency=(req.finish_t - req.submit_t
-                                 + req.transfer_charge),
-                        overlap=req.overlaps, finish_time=now,
-                        transfer_wait=req.transfer_wait,
-                        transfer_floor=req.transfer_floor))
-                    completed += 1
-        # controller telemetry poll (every tick at test scale)
-        ttft_p99 = self.metrics.histogram("ttft", window_s=300.0).p99(self._now())
-        self.control.observe(ttft_p99, self._now())
+        for rid, tok, done in out:
+            req, worker, _slot = self.running[rid]
+            now = self._now()
+            req.output.append(tok)
+            # per-token ITL: every decode step contributes a sample, so
+            # the ITL histogram (and the Planner's v_ITL) is live on
+            # the engine path, not just TTFT
+            self.metrics.histogram("itl", window_s=300.0).observe(
+                now - req.last_token_t, now)
+            req.last_token_t = now
+            if done:
+                # slot already released inside dec.step() (returned-slot
+                # contract: done=True means re-admittable this tick)
+                req.finish_t = now
+                del self.running[rid]
+                self.done.append(req)
+                self.control.router.on_complete(worker, req.tokens)
+                self.metrics.histogram("ttft", window_s=300.0).observe(
+                    req.charged_ttft, now)
+                self.poa.record(CompletedRequest(
+                    request_id=rid, worker=worker,
+                    latency=(req.finish_t - req.submit_t
+                             + req.transfer_charge),
+                    overlap=req.overlaps, finish_time=now,
+                    transfer_wait=req.transfer_wait,
+                    transfer_floor=req.transfer_floor))
+                completed += 1
         return completed
 
     def run_until_done(self, max_ticks: int = 10_000) -> List[ServeRequest]:

@@ -24,7 +24,6 @@ cost the routing game is about.  A hop between chips does not exist yet.
 from __future__ import annotations
 
 import functools
-import time
 from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
@@ -34,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.radix import BLOCK_SIZE, block_hashes
+from repro.core.tracing import Tracer
 from repro.models.model import Model
 from repro.serving.paging import PageAllocator
 
@@ -66,8 +66,9 @@ class PrefillStats:
 class PrefillEngine:
     def __init__(self, model: Model, params, max_len: int,
                  cache_entries: int = 16, block_size: int = BLOCK_SIZE,
-                 max_batch: int = 8):
+                 max_batch: int = 8, tracer: Optional[Tracer] = None):
         self.model = model
+        self.tracer = tracer if tracer is not None else Tracer()
         self.params = params
         self.max_len = max_len
         self.block_size = block_size
@@ -77,12 +78,19 @@ class PrefillEngine:
         # Batch widths are padded to powers of two so the jit shape set
         # stays O(log max_batch) per length bucket.
         self.max_batch = max(1, max_batch)
-        self._prefill = jax.jit(
-            lambda p, batch: model.prefill(p, batch, max_len=max_len))
-        self._prefill_batched = jax.jit(
-            lambda p, toks, lens: model.prefill_batched(p, toks, lens,
-                                                        max_len=max_len))
-        # start is traced (one compile per suffix length, not per offset)
+
+        # each jitted pass is named: the profiler's trace shows its module
+        # as jit_<name>
+        def prefill_pass(p, batch):
+            return model.prefill(p, batch, max_len=max_len)
+
+        def prefill_batched(p, toks, lens):
+            return model.prefill_batched(p, toks, lens, max_len=max_len)
+
+        self._prefill = jax.jit(prefill_pass)
+        self._prefill_batched = jax.jit(prefill_batched)
+        # start is traced (one compile per suffix length, not per offset);
+        # the bound method names the module jit_prefill_resume
         self._resume = jax.jit(model.prefill_resume)
         # prefix cache: full hash chain of a completed prompt pass → its
         # cache bundle (K/V valid for every position of that prompt).  A
@@ -222,7 +230,7 @@ class PrefillEngine:
     # ----------------------------------------------------------- prefill ----
 
     def prefill(self, tokens: Sequence[int], extras: Optional[dict] = None,
-                hashes: Optional[Sequence[int]] = None):
+                hashes: Optional[Sequence[int]] = None, rid=None):
         """Single-request prompt pass → (last_logits (V,), cache bundle).
 
         Resumes from the longest cached block prefix when possible; a miss
@@ -241,19 +249,22 @@ class PrefillEngine:
             start = self._resume_start(len(tokens), m)
             if start == 0:
                 donor = None
-        t0 = time.perf_counter()
-        if start > 0:
-            suffix = jnp.asarray(tokens[start:], jnp.int32)[None, :]
-            logits, caches = self._resume(self.params, donor, suffix,
-                                          jnp.int32(start))
-        else:
-            batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None, :]}
-            if extras:
-                batch.update({k: jnp.asarray(v)[None]
-                              for k, v in extras.items()})
-            logits, caches = self._prefill(self.params, batch)
-        logits = np.asarray(logits[0])
-        wall = time.perf_counter() - t0
+        with self.tracer.timed("prefill.pass") as sp:
+            if start > 0:
+                suffix = jnp.asarray(tokens[start:], jnp.int32)[None, :]
+                logits, caches = self._resume(self.params, donor, suffix,
+                                              jnp.int32(start))
+            else:
+                batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None, :]}
+                if extras:
+                    batch.update({k: jnp.asarray(v)[None]
+                                  for k, v in extras.items()})
+                logits, caches = self._prefill(self.params, batch)
+            logits = np.asarray(logits[0])
+        wall = sp.seconds
+        if self.tracer.enabled:
+            sp.detail = _pass_detail("resume" if start > 0 else "cold", 1,
+                                     len(tokens) - start, 0, [rid])
         st = self.stats
         st.requests += 1
         st.total_blocks += len(hashes)
@@ -270,7 +281,8 @@ class PrefillEngine:
 
     def prefill_many(self, requests: Sequence[Tuple[Sequence[int],
                                                     Optional[dict],
-                                                    Optional[Sequence[int]]]]
+                                                    Optional[Sequence[int]]]],
+                     rids: Optional[Sequence[object]] = None
                      ) -> List[Tuple[np.ndarray, object, int]]:
         """Batched prompt passes across queued requests.
 
@@ -288,7 +300,8 @@ class PrefillEngine:
         ragged pass over the per-row lengths vector.  Identical prompts
         inside one call collapse onto a single batch row.  Every grouped
         pass is pinned logit-comparable to the sequential path by
-        ``tests/test_engine_batching.py``."""
+        ``tests/test_engine_batching.py``.  ``rids`` (aligned with
+        ``requests``) only label the ``prefill.pass`` spans."""
         n = len(requests)
         results: List[Optional[Tuple[np.ndarray, object, int]]] = [None] * n
         st = self.stats
@@ -303,7 +316,9 @@ class PrefillEngine:
             if extras:
                 # multimodal inputs carry per-request arrays; keep them on
                 # the exact single-request path
-                logits, caches = self.prefill(tokens, extras, hashes=hashes)
+                logits, caches = self.prefill(
+                    tokens, extras, hashes=hashes,
+                    rid=rids[i] if rids is not None else None)
                 results[i] = (logits, caches, 0)
                 continue
             key = tuple(tokens)
@@ -331,12 +346,12 @@ class PrefillEngine:
         for plen, group in cold.items():
             for c0 in range(0, len(group), self.max_batch):
                 self._run_cold_chunk(plen, group[c0:c0 + self.max_batch],
-                                     results)
+                                     results, rids)
         # --- resume groups: one stacked-donor pass per chunk --------------
         for (start, _), group in resume.items():
             for c0 in range(0, len(group), self.max_batch):
                 self._run_resume_chunk(start, group[c0:c0 + self.max_batch],
-                                       results)
+                                       results, rids)
         for i, j in alias:
             results[i] = results[j]
             st.requests += 1
@@ -344,26 +359,30 @@ class PrefillEngine:
             st.total_tokens += len(requests[i][0])
         return results  # fully populated: every request hit exactly one path
 
-    def _run_cold_chunk(self, plen: int, group, results) -> None:
+    def _run_cold_chunk(self, plen: int, group, results, rids=None) -> None:
         w = self._width(len(group))
         toks = np.zeros((w, plen), np.int32)
         lens = np.ones((w,), np.int32)
         for r, (_, tokens, _) in enumerate(group):
             toks[r, :len(tokens)] = tokens
             lens[r] = len(tokens)
-        t0 = time.perf_counter()
-        logits, caches = self._prefill_batched(
-            self.params, jnp.asarray(toks), jnp.asarray(lens))
-        logits = np.asarray(logits)
-        wall = time.perf_counter() - t0
+        with self.tracer.timed("prefill.pass") as sp:
+            logits, caches = self._prefill_batched(
+                self.params, jnp.asarray(toks), jnp.asarray(lens))
+            logits = np.asarray(logits)
         st = self.stats
         st.batches += 1
-        st.wall_s += wall
+        st.wall_s += sp.seconds
         if len(group) > 1:
             st.batched_requests += len(group)
         # pad overhead: right-padding inside rows + power-of-two pad rows
-        st.padded_tokens += int(np.sum(plen - lens[:len(group)])) \
+        padded = int(np.sum(plen - lens[:len(group)])) \
             + (w - len(group)) * plen
+        st.padded_tokens += padded
+        if self.tracer.enabled:
+            sp.detail = _pass_detail(
+                "cold", w, int(np.sum(lens[:len(group)])), padded,
+                _rids_of(rids, [i for i, _, _ in group]))
         for r, (i, tokens, hashes) in enumerate(group):
             st.requests += 1
             st.total_blocks += len(hashes)
@@ -376,7 +395,8 @@ class PrefillEngine:
                 self._store(hashes, jax.tree.map(
                     lambda a, r=r: a[:, r:r + 1], caches))
 
-    def _run_resume_chunk(self, start: int, group, results) -> None:
+    def _run_resume_chunk(self, start: int, group, results,
+                          rids=None) -> None:
         w = self._width(len(group))
         suffixes = np.stack(
             [np.asarray(tokens[start:], np.int32) for _, tokens, _, _ in group]
@@ -384,17 +404,22 @@ class PrefillEngine:
         donors = [d for *_, d in group] + [group[0][3]] * (w - len(group))
         stacked = donors[0] if w == 1 else jax.tree.map(
             lambda *xs: jnp.concatenate(xs, axis=1), *donors)
-        t0 = time.perf_counter()
-        logits, caches = self._resume(self.params, stacked,
-                                      jnp.asarray(suffixes), jnp.int32(start))
-        logits = np.asarray(logits)
-        wall = time.perf_counter() - t0
+        with self.tracer.timed("prefill.pass") as sp:
+            logits, caches = self._resume(self.params, stacked,
+                                          jnp.asarray(suffixes),
+                                          jnp.int32(start))
+            logits = np.asarray(logits)
         st = self.stats
         st.batches += 1
-        st.wall_s += wall
+        st.wall_s += sp.seconds
         if len(group) > 1:
             st.batched_requests += len(group)
-        st.padded_tokens += (w - len(group)) * suffixes.shape[1]
+        padded = (w - len(group)) * suffixes.shape[1]
+        st.padded_tokens += padded
+        if self.tracer.enabled:
+            sp.detail = _pass_detail(
+                "resume", w, len(group) * suffixes.shape[1], padded,
+                _rids_of(rids, [i for i, *_ in group]))
         for r, (i, tokens, hashes, _) in enumerate(group):
             st.requests += 1
             st.total_blocks += len(hashes)
@@ -406,6 +431,18 @@ class PrefillEngine:
             if hashes:
                 self._store(hashes, jax.tree.map(
                     lambda a, r=r: a[:, r:r + 1], caches))
+
+
+def _pass_detail(kind: str, width: int, computed: int, padded: int,
+                 rids: list) -> dict:
+    """Detail of a ``prefill.pass`` span: cold or resume, batch width,
+    prompt tokens computed and pad tokens run, the requests served."""
+    return {"kind": kind, "width": width, "computed": computed,
+            "padded": padded, "rids": rids}
+
+
+def _rids_of(rids: Optional[Sequence[object]], idx: List[int]) -> list:
+    return [rids[i] for i in idx] if rids is not None else [None] * len(idx)
 
 
 def _shared_depth(a: Sequence[int], b: Sequence[int]) -> int:
@@ -456,11 +493,13 @@ class DecodeEngine:
                  worker_id: int = 0, resident_blocks: int = 4096,
                  decode_impl: str = "pallas",
                  num_pages: Optional[int] = None,
-                 page_block: int = BLOCK_SIZE):
+                 page_block: int = BLOCK_SIZE,
+                 tracer: Optional[Tracer] = None):
         if decode_impl not in ("pallas", "sdpa") + PAGED_IMPLS:
             raise ValueError(f"unknown decode_impl {decode_impl!r}")
         self.model = model
         self.params = params
+        self.tracer = tracer if tracer is not None else Tracer()
         self.num_slots = num_slots
         self.max_len = max_len
         self.worker_id = worker_id
@@ -484,9 +523,13 @@ class DecodeEngine:
             # specialization of the decode step; warmup can pre-compile
             # the ladder).  Unmapped entries stay 0 — the trash page.
             self.page_table = np.zeros((num_slots, 1), np.int32)
-            self._adopt = jax.jit(
-                functools.partial(adopt_prefill_pages, block=page_block),
-                donate_argnums=0)
+
+            # named as the dense path's hop: one module name, jit_kv_hop
+            def kv_hop(pool, row, page_ids):
+                return adopt_prefill_pages(pool, row, page_ids,
+                                           block=page_block)
+
+            self._adopt = jax.jit(kv_hop, donate_argnums=0)
         else:
             self.allocator = None
             self.caches = model.cache_init(num_slots, max_len)
@@ -641,34 +684,36 @@ class DecodeEngine:
         worst case stays reserved for mid-generation :meth:`step` growth.
         Callers that skipped :meth:`reserve` must gate on
         :meth:`can_admit` — an ungated paged admit raises."""
-        if self.paged:
-            n_map = self.pages_for_prompt(prompt_len)
-            pages = self.allocator.admit(
-                slot, n_map, self.pages_for_request(prompt_len, max_new))
-            if pages is None:
-                raise RuntimeError(
-                    f"page pool exhausted admitting {request_id!r} to slot "
-                    f"{slot}: gate admission on can_admit()")
-            self._widen_table(self._table_width(len(pages)))
-            self.page_table[slot, :] = 0
-            self.page_table[slot, :len(pages)] = pages
-            row = jax.tree.map(
-                lambda a: jax.lax.slice_in_dim(a, src_row, src_row + 1,
-                                               axis=1), prefill_caches)
-            self.caches = self._adopt(self.caches, row,
-                                      jnp.asarray(pages, jnp.int32))
-        else:
-            self.caches = _insert_cache(self.caches, prefill_caches, slot,
-                                        src_row)
-        s = self.slots[slot]
-        s.active = True
-        s.request_id = request_id
-        s.length = prompt_len
-        s.generated = [int(first_token)]
-        s.max_new = max_new
-        self.tokens[slot, 0] = first_token
-        moved = self._touch_blocks(hashes)
-        self.transferred_blocks += moved
+        with self.tracer.span("hop", rid=request_id) as sp:
+            if self.paged:
+                n_map = self.pages_for_prompt(prompt_len)
+                pages = self.allocator.admit(
+                    slot, n_map, self.pages_for_request(prompt_len, max_new))
+                if pages is None:
+                    raise RuntimeError(
+                        f"page pool exhausted admitting {request_id!r} to "
+                        f"slot {slot}: gate admission on can_admit()")
+                self._widen_table(self._table_width(len(pages)))
+                self.page_table[slot, :] = 0
+                self.page_table[slot, :len(pages)] = pages
+                row = jax.tree.map(
+                    lambda a: jax.lax.slice_in_dim(a, src_row, src_row + 1,
+                                                   axis=1), prefill_caches)
+                self.caches = self._adopt(self.caches, row,
+                                          jnp.asarray(pages, jnp.int32))
+            else:
+                self.caches = kv_hop(self.caches, prefill_caches, slot,
+                                     src_row)
+            s = self.slots[slot]
+            s.active = True
+            s.request_id = request_id
+            s.length = prompt_len
+            s.generated = [int(first_token)]
+            s.max_new = max_new
+            self.tokens[slot, 0] = first_token
+            moved = self._touch_blocks(hashes)
+            self.transferred_blocks += moved
+            sp.detail = {"worker": self.worker_id, "blocks": moved}
         return moved
 
     def release(self, slot: int):
@@ -715,44 +760,55 @@ class DecodeEngine:
         same tick, and callers must NOT call :meth:`release` again."""
         if not any(s.active and s.generated for s in self.slots):
             return []
-        # reserved-but-unadmitted slots (active, no first token yet) carry
-        # no valid cache state: they decode as length-0 rows and their
-        # output is skipped below
-        lengths = jnp.asarray([s.length if s.active else 0
-                               for s in self.slots], jnp.int32)
-        if self.paged:
-            # growth pre-pass: this tick writes each admitted slot's KV at
-            # position s.length — if that crosses into an unmapped block,
-            # map one page from the slot's reservation (and widen the
-            # table to the next ladder width when the row is full).
+        tr = self.tracer
+        # span detail: the positions each live row attends over this step
+        detail = {"worker": self.worker_id,
+                  "lengths": [s.length + 1 for s in self.slots
+                              if s.active and s.generated]} \
+            if tr.enabled else None
+        with tr.span("decode_step", detail=detail):
+            with tr.span("decode.dispatch"):
+                # reserved-but-unadmitted slots (active, no first token
+                # yet) carry no valid cache state: they decode as length-0
+                # rows and their output is skipped below
+                lengths = jnp.asarray([s.length if s.active else 0
+                                       for s in self.slots], jnp.int32)
+                if self.paged:
+                    # growth pre-pass: this tick writes each admitted slot's
+                    # KV at position s.length — if that crosses into an
+                    # unmapped block, map one page from the slot's
+                    # reservation (and widen the table to the next ladder
+                    # width when the row is full).
+                    for i, s in enumerate(self.slots):
+                        if not s.active or not s.generated:
+                            continue
+                        j = s.length // self.page_block
+                        if j >= len(self.allocator.owned[i]):
+                            page = self.allocator.grow(i)
+                            self._widen_table(self._table_width(j + 1))
+                            self.page_table[i, j] = page
+                    logits, self.caches = self._decode(
+                        self.params, self.caches, jnp.asarray(self.tokens),
+                        lengths, page_table=jnp.asarray(self.page_table))
+                else:
+                    logits, self.caches = self._decode(
+                        self.params, self.caches, jnp.asarray(self.tokens),
+                        lengths)
+            with tr.span("decode.wait"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            out = []
             for i, s in enumerate(self.slots):
                 if not s.active or not s.generated:
                     continue
-                j = s.length // self.page_block
-                if j >= len(self.allocator.owned[i]):
-                    page = self.allocator.grow(i)
-                    self._widen_table(self._table_width(j + 1))
-                    self.page_table[i, j] = page
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(self.tokens), lengths,
-                page_table=jnp.asarray(self.page_table))
-        else:
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(self.tokens), lengths)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        out = []
-        for i, s in enumerate(self.slots):
-            if not s.active or not s.generated:
-                continue
-            tok = int(nxt[i])
-            s.generated.append(tok)
-            s.length += 1
-            self.tokens[i, 0] = tok
-            done = (len(s.generated) >= s.max_new + 1
-                    or s.length >= self.max_len - 1)
-            out.append((s.request_id, tok, done))
-            if done:
-                self.release(i)   # slot is re-admittable this same tick
+                tok = int(nxt[i])
+                s.generated.append(tok)
+                s.length += 1
+                self.tokens[i, 0] = tok
+                done = (len(s.generated) >= s.max_new + 1
+                        or s.length >= self.max_len - 1)
+                out.append((s.request_id, tok, done))
+                if done:
+                    self.release(i)   # re-admittable this same tick
         return out
 
 
@@ -790,7 +846,7 @@ def adopt_prefill_pages(pool, row_bundle, page_ids, *, block: int):
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _insert_cache(dst, src, slot, src_row):
+def kv_hop(dst, src, slot, src_row):
     """Write row ``src_row`` of a prefill cache bundle into decode slot
     ``slot`` (batched prefill emits multi-row bundles; the sequential path
     keeps row 0).  ``dst`` is donated, so the decoder cache is updated in
