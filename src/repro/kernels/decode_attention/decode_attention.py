@@ -14,6 +14,12 @@ trailing cache entries, and they clamp the K/V index map so blocks past a
 row's length are never fetched (a repeated block index skips the DMA).  The
 cache length need not be a multiple of ``blk_k``: the ragged last block is
 masked by length like any other position past it.
+
+The K/V operands are a stack of per-layer caches ``(P, B, T, K, hd)`` and
+a second scalar-prefetch operand names the layer read: the index map
+points every block at that layer, so the decode step hands the kernel the
+whole cache its layer scan carries without slicing a layer out first.  A
+single layer's cache is the stack of one.
 """
 from __future__ import annotations
 
@@ -28,8 +34,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            blk_k: int, sm_scale: float):
+def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+            acc_scr, *, blk_k: int, sm_scale: float):
     b_ = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -73,31 +79,33 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def decode_attention_pallas(q, k, v, lengths, *, blk_k=256, interpret=False):
-    """q: (B,K,G,hd) grouped queries; k,v: (B,T,K,hd); lengths: (B,) int32
-    valid KV entries per row (0 ≤ length ≤ T)."""
+def decode_attention_pallas(q, k, v, lengths, layer, *, blk_k=256,
+                            interpret=False):
+    """q: (B,K,G,hd) grouped queries; k,v: (P,B,T,K,hd) stacked caches;
+    lengths: (B,) int32 valid KV entries per row (0 ≤ length ≤ T); layer:
+    (1,) int32, the index into P read."""
     b, kh, g, hd = q.shape
-    t = k.shape[1]
+    t = k.shape[2]
     blk_k = min(blk_k, t)
     nk = pl.cdiv(t, blk_k)
     sm_scale = 1.0 / np.sqrt(hd)
     kernel = functools.partial(_kernel, blk_k=blk_k, sm_scale=sm_scale)
 
-    def kv_map(b_, k_, lens):
+    def kv_map(b_, k_, lens, lyr):
         # blocks wholly past the row's length repeat its last live block
         last = jnp.maximum(lens[b_] - 1, 0) // blk_k
-        return (b_, jnp.minimum(k_, last), 0, 0)
+        return (lyr[0], b_, jnp.minimum(k_, last), 0, 0)
 
+    def row_map(b_, k_, lens, lyr):
+        return (b_, 0, 0, 0)
+
+    # the layer axis is squeezed: the body sees (1, blk_k, K, hd) blocks
+    kv_spec = pl.BlockSpec((None, 1, blk_k, kh, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nk),
-        in_specs=[
-            pl.BlockSpec((1, kh, g, hd), lambda b_, k_, lens: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, blk_k, kh, hd), kv_map),
-            pl.BlockSpec((1, blk_k, kh, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, kh, g, hd),
-                               lambda b_, k_, lens: (b_, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, kh, g, hd), row_map), kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, kh, g, hd), row_map),
         scratch_shapes=[
             pltpu.VMEM((kh, g, 1), jnp.float32),
             pltpu.VMEM((kh, g, 1), jnp.float32),
@@ -110,4 +118,4 @@ def decode_attention_pallas(q, k, v, lengths, *, blk_k=256, interpret=False):
         out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(lengths, q, k, v)
+    )(lengths, layer, q, k, v)

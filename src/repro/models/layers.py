@@ -139,7 +139,8 @@ def _sdpa_chunked(q, k, v, qpos, q_per_kv, *, kind, kv_lengths=None,
 
 def attention(params, x, cfg, *, positions=None, kv_cache=None, write_index=None,
               kv_source=None, causal=True, kv_lengths=None, use_rope=True,
-              use_flash=False, decode_impl="sdpa", page_table=None):
+              use_flash=False, decode_impl="sdpa", page_table=None,
+              layer=None):
     """General GQA attention.
 
     x: (B,S,D) hidden states.
@@ -161,6 +162,10 @@ def attention(params, x, cfg, *, positions=None, kv_cache=None, write_index=None
         the XLA causal path (bit-compatible with "sdpa", CPU-meaningful).
     page_table: (B, W) int32 page ids per slot (paged decode only).
         Unmapped entries point at the trash page 0 and are masked by length.
+    layer: int32 scalar — a single-token dense step on the stacked caches
+        of every layer, dict(k=(P,B,T,K,hd), v=...), that the layer scan
+        carries: row b's K/V is written at ``[layer, b, write_index[b]]``
+        in place and attention reads layer ``layer`` of the stack.
     Returns (out, new_kv_cache_or_None).
     """
     b, s, d = x.shape
@@ -185,6 +190,35 @@ def attention(params, x, cfg, *, positions=None, kv_cache=None, write_index=None
         qp = jnp.broadcast_to(positions[None].astype(jnp.int32), (b, s))
     else:
         qp = positions.astype(jnp.int32)
+
+    if kv_cache is not None and layer is not None:
+        # One position per row into the carried stack, in place: the
+        # step's only cache traffic is B rows.  A write index past the
+        # cache is dropped, as the masked rewrite below drops it.
+        if s != 1:
+            raise ValueError("the stacked-cache write takes one token a row")
+        k = shard(k, "decode_batch", None, "kv_heads", "kv_head_dim")
+        v = shard(v, "decode_batch", None, "kv_heads", "kv_head_dim")
+        widx = jnp.broadcast_to(jnp.asarray(write_index, jnp.int32), (b,))
+        rows = jnp.arange(b, dtype=jnp.int32)
+        ck, cv = kv_cache["k"], kv_cache["v"]
+        ck = ck.at[layer, rows, widx].set(k[:, 0].astype(ck.dtype),
+                                          mode="drop", unique_indices=True)
+        cv = cv.at[layer, rows, widx].set(v[:, 0].astype(cv.dtype),
+                                          mode="drop", unique_indices=True)
+        if decode_impl == "pallas":
+            from repro.kernels.decode_attention import ops as decode_ops
+            out = decode_ops.decode_attention_stacked(
+                q[:, 0], ck, cv, qp[:, 0] + 1, layer)[:, None]
+        else:
+            kl = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+            vl = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+            out = _sdpa_chunked(q, kl.astype(COMPUTE_DTYPE),
+                                vl.astype(COMPUTE_DTYPE), qp,
+                                cfg.q_heads_per_kv, kind="causal")
+        out = jnp.einsum("bshk,hkd->bsd", out,
+                         params["wo"].astype(COMPUTE_DTYPE))
+        return shard(out, "batch", "seq", "act_embed"), {"k": ck, "v": cv}
 
     if kv_cache is not None and decode_impl in ("paged", "paged_sdpa"):
         # Paged single-token decode: the cache is the global page pool

@@ -147,9 +147,11 @@ class Model:
 
     def _block_apply(self, desc, bp, x, bc, *, positions, write_index,
                      enc_out, causal=True, decode_impl="sdpa",
-                     page_table=None):
-        """Apply one block. bc (the block cache) is None in train mode.
-        Returns (x, new_block_cache, moe_aux or None)."""
+                     page_table=None, layer=None):
+        """Apply one block. bc (the block cache) is None in train mode; with
+        ``layer`` it is the stacked cache of every period (see
+        :meth:`_run_stack`).  Returns (x, new_block_cache, moe_aux or
+        None)."""
         cfg = self.cfg
         is_step = x.shape[1] == 1 and bc is not None
         nc = {}
@@ -159,7 +161,7 @@ class Model:
                                 write_index=write_index, causal=causal,
                                 use_flash=self.use_flash,
                                 decode_impl=decode_impl,
-                                page_table=page_table)
+                                page_table=page_table, layer=layer)
             if bc is not None:
                 nc["kv"] = kv
             x = x + h
@@ -212,13 +214,17 @@ class Model:
 
     def _run_stack(self, stack, x, *, caches=None, positions=None,
                    write_index=None, enc_out=None, causal=True, remat=False,
-                   decode_impl="sdpa", page_table=None):
-        """lax.scan over periods. Returns (x, new_caches_or_None, aux_sum)."""
+                   decode_impl="sdpa", page_table=None, carry_caches=False):
+        """lax.scan over periods. Returns (x, new_caches_or_None, aux_sum).
+
+        The caches go through the scan's ``xs`` and come back as ``ys``, a
+        fresh stack that XLA fills with every layer's whole cache.  With
+        ``carry_caches`` (a single-token step, :meth:`kv_write_in_place`)
+        they ride in the carry instead, with the period index in ``xs``,
+        and each layer writes its one new position into them in place."""
         collect = caches is not None
 
-        def body(carry, per):
-            xx = carry
-            pp, pc = per if collect else (per, None)
+        def blocks(pp, xx, pc, layer):
             new_c = {}
             aux_sum = jnp.zeros((), jnp.float32)
             for i, desc in enumerate(self.descs):
@@ -226,16 +232,31 @@ class Model:
                 xx, ncb, aux = self._block_apply(
                     desc, pp[f"p{i}"], xx, bc, positions=positions,
                     write_index=write_index, enc_out=enc_out, causal=causal,
-                    decode_impl=decode_impl, page_table=page_table)
+                    decode_impl=decode_impl, page_table=page_table,
+                    layer=layer)
                 new_c[f"p{i}"] = ncb
                 if aux is not None:
                     aux_sum = aux_sum + aux["moe_aux_loss"]
-            return xx, ((new_c, aux_sum) if collect else aux_sum)
+            return xx, new_c, aux_sum
+
+        def body(carry, per):
+            if carry_caches:
+                (xx, cc), (pp, layer) = carry, per
+                xx, cc, aux = blocks(pp, xx, cc, layer)
+                return (xx, cc), aux
+            pp, pc = per if collect else (per, None)
+            xx, new_c, aux = blocks(pp, carry, pc, None)
+            return xx, ((new_c, aux) if collect else aux)
 
         if remat:
             body = jax.checkpoint(body)
         unroll = flags.scan_unroll(self.n_periods)
-        if collect:
+        if carry_caches:
+            layers = jnp.arange(self.n_periods, dtype=jnp.int32)
+            (x, new_caches), aux = jax.lax.scan(body, (x, caches),
+                                                (stack, layers),
+                                                unroll=unroll)
+        elif collect:
             x, (new_caches, aux) = jax.lax.scan(body, x, (stack, caches),
                                                 unroll=unroll)
         else:
@@ -351,6 +372,16 @@ class Model:
         that has no block-granular form."""
         return (all(d.mixer == "attn" and not d.cross for d in self.descs)
                 and self.cfg.family not in ("encdec", "vlm"))
+
+    def kv_write_in_place(self, decode_impl: str) -> bool:
+        """Whether :meth:`decode` carries the dense stacked caches through
+        the layer scan and writes each row's new K/V into them in place,
+        rather than rebuilding every layer's cache.  It does for a pure
+        causal-attention stack (:attr:`supports_paged_decode`), whose
+        caches hold nothing but K/V, on a dense ``decode_impl``; the paged
+        impls write into their page pool."""
+        return (self.supports_paged_decode
+                and decode_impl not in ("paged", "paged_sdpa"))
 
     def prefill(self, params, batch, max_len=None):
         """Process the prompt; returns (last_logits (B,V), caches)."""
@@ -492,7 +523,9 @@ class Model:
             positions = cur[:, None]
         x, new_caches, _ = self._run_stack(
             params["stack"], x, caches=caches, positions=positions,
-            write_index=cur, decode_impl=decode_impl, page_table=page_table)
+            write_index=cur, decode_impl=decode_impl, page_table=page_table,
+            carry_caches=(tokens.shape[1] == 1
+                          and self.kv_write_in_place(decode_impl)))
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x,
                             params["unembed"].astype(L.COMPUTE_DTYPE))

@@ -536,6 +536,9 @@ class DecodeEngine:
         self._decode = jax.jit(
             functools.partial(model.decode, decode_impl=decode_impl),
             donate_argnums=1)
+        # the step writes each row's new K/V into the donated cache in
+        # place (a dense pure-attention stack) instead of rebuilding it
+        self.kv_write_in_place = model.kv_write_in_place(decode_impl)
         # KV-block residency (the worker's G1 view): bounded LRU over the
         # block hashes this worker has admitted.  The transfer() hop is
         # charged only for blocks NOT in this set — a cache-warm routing
@@ -761,10 +764,12 @@ class DecodeEngine:
         if not any(s.active and s.generated for s in self.slots):
             return []
         tr = self.tracer
-        # span detail: the positions each live row attends over this step
+        # span detail: the positions each live row attends over this step,
+        # and which cache write the step took
         detail = {"worker": self.worker_id,
                   "lengths": [s.length + 1 for s in self.slots
-                              if s.active and s.generated]} \
+                              if s.active and s.generated],
+                  "kv_write_in_place": self.kv_write_in_place} \
             if tr.enabled else None
         with tr.span("decode_step", detail=detail):
             with tr.span("decode.dispatch"):

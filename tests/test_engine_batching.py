@@ -333,3 +333,72 @@ def test_decode_impl_logits_parity(reduced_model):
         spread = float(ls.max() - ls.min())
         assert float(np.abs(lp - ls).max()) < 0.02 * spread, step
         tok = int(np.argmax(ls))
+
+
+# ------------------------------------------------- in-place KV write --------
+# A dense decode step of a pure-attention stack carries the stacked cache
+# through the layer scan and writes each row's one new position in place;
+# the path it replaced rebuilt every layer's cache through the scan's
+# xs/ys.  Both do the same arithmetic on the same values, so the contract
+# is exact: same tokens, same fp32 logits, same cache at every live
+# position.
+
+
+def _logged_steps(engine):
+    """Record the fp32 logits of every jitted step the engine runs."""
+    logged, step = [], engine._decode
+
+    def run(*args, **kwargs):
+        logits, caches = step(*args, **kwargs)
+        logged.append(np.asarray(logits))
+        return logits, caches
+
+    engine._decode = run
+    return logged
+
+
+@pytest.mark.parametrize("decode_impl", ["pallas", "sdpa"])
+def test_differential_kv_write_in_place(reduced_model, decode_impl):
+    """Fourteen steps of a ragged slot batch: a row that runs until
+    ``max_len - 1``, a short row whose slot is reused in the tick it frees,
+    a reserved-but-unadmitted slot and an inactive one."""
+    from repro.models.model import Model
+    cfg, model, params = reduced_model
+    max_len = 48
+    rebuilt = Model(cfg)                       # the xs/ys path, same params
+    rebuilt.kv_write_in_place = lambda impl: False
+    engines = [DecodeEngine(m, params, num_slots=4, max_len=max_len,
+                            decode_impl=decode_impl)
+               for m in (model, rebuilt)]
+    assert [e.kv_write_in_place for e in engines] == [True, False]
+    logs = [_logged_steps(e) for e in engines]
+    pre = PrefillEngine(model, params, max_len=max_len, cache_entries=0)
+
+    def admit(slot, rid, template, n, max_new):
+        logits, caches = pre.prefill(_toks(cfg, template, n))
+        for e in engines:
+            e.admit(slot, rid, caches, int(np.argmax(logits)),
+                    prompt_len=n, max_new=max_new)
+
+    admit(0, "long", 0, 34, 100)       # done at length max_len - 1
+    admit(1, "short", 1, 20, 2)        # frees slot 1 on step 2
+    for e in engines:
+        e.reserve(2, "held")           # slot 3 stays inactive
+    reused = False
+    for step in range(14):
+        outs = [e.step() for e in engines]
+        assert outs[0] == outs[1], step
+        np.testing.assert_array_equal(logs[0][-1], logs[1][-1])
+        caches = [jax.tree.leaves(e.caches) for e in engines]
+        for i, s in enumerate(engines[0].slots):
+            if s.active and s.generated:
+                for a, b in zip(*caches):
+                    np.testing.assert_array_equal(
+                        np.asarray(a[:, i, :s.length]),
+                        np.asarray(b[:, i, :s.length]))
+        done = {rid for rid, _, fin in outs[0] if fin}
+        if "short" in done:
+            admit(1, "again", 2, 27, 20)    # same-tick slot reuse
+            reused = True
+        assert ("long" in done) == (step == 12), step
+    assert reused and len(logs[0]) == 14

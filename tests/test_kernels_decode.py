@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.decode_attention.ops import (decode_attention,
+                                                decode_attention_stacked)
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 SHAPES = [
@@ -68,3 +69,23 @@ def test_decode_agrees_with_flash_last_row():
                            interpret=True)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full[:, -1]),
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("layers", [1, 3])
+def test_stacked_entry_reads_one_layer(layers):
+    """The stacked-cache entry at layer ``l`` is the per-layer entry on
+    ``k[l]``, ``v[l]``, bit for bit: a cache not a multiple of the 256-row
+    block, and rows of length 0, 1 and T."""
+    b, t, h, kh, hd = 3, 1156, 4, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(layers), 3)
+    q = jax.random.normal(ks[0], (b, h, hd)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (layers, b, t, kh, hd)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (layers, b, t, kh, hd)).astype(jnp.bfloat16)
+    lengths = jnp.asarray([0, 1, t], jnp.int32)
+    for l in range(layers):
+        out = decode_attention_stacked(q, k, v, lengths, jnp.int32(l),
+                                       interpret=True)
+        one = decode_attention(q, k[l], v[l], lengths, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(one, np.float32))
+        assert not np.asarray(out[0]).any()          # length 0: zeros

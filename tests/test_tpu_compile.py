@@ -21,6 +21,7 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.models import build_model
+from test_decode_kv_in_place import cache_aliased, cache_rewrites
 
 CFG = get_config("phi4-mini-3.8b")
 H, K, HD = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
@@ -87,7 +88,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
 @pytest.mark.parametrize("impl", ["pallas", "paged"])
 def test_decode_step_compiles_for_v5e(one_chip, impl):
     """The served decode step (two layers at published widths) compiles
-    with its kernel inside, for the slot batch the engine hands it."""
+    with its kernel inside, for the slot batch the engine hands it; the
+    dense step neither copies nor rewrites its cache whole."""
     cfg = dataclasses.replace(CFG, num_layers=2)
     model = build_model(cfg)
 
@@ -108,4 +110,9 @@ def test_decode_step_compiles_for_v5e(one_chip, impl):
         args[1] = place(model.cache_init(BATCH, CACHE_LEN, abstract=True))
     step = functools.partial(model.decode, decode_impl=impl)
     compiled = jax.jit(step, donate_argnums=1).lower(*args, **kw).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    if impl == "pallas":   # each row's K/V written into the cache in place
+        leaves = jax.tree.leaves(args[1])
+        assert cache_rewrites(hlo, leaves[0].shape) == []
+        assert cache_aliased(hlo, len(jax.tree.leaves(params)), len(leaves))

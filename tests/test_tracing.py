@@ -171,6 +171,7 @@ def test_every_span_is_recorded_with_its_parent_and_rid(served):
                   for rid in s.detail["rids"] if rid) == sorted(rids)
     for s in by["decode_step"]:
         assert s.detail["worker"] in (0, 1) and s.detail["lengths"]
+        assert s.detail["kv_write_in_place"] is True
     assert sum(s.detail for s in by["complete"]) == 5
     assert all(isinstance(s.detail, int) for s in by["schedule"])
 
