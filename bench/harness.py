@@ -15,13 +15,19 @@ traffic mix (``bench/traffic/<traffic>.json``).  The run:
    then a drain for the requests due in the window;
 4. frees the program's state and compares a sample of finished requests,
    drawn from the seed with the longest among them, with the float32
-   reference (``bench/model_ref.py``): ``correct`` holds where no served
-   token's reference logit lies further below the reference's best than the
+   reference of the configuration's architecture module
+   (``bench/<reference>.py``, ``bench/model_ref.py`` where the
+   configuration names none): ``correct`` holds where no served token's
+   reference logit lies further below the reference's best than the
    configuration's limit.
 
-``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
-per-layer metrics, read by ``bench/metrics/<metric>.py`` from host spans
-over the window and a profiler trace of its last seconds.
+The architecture module also draws the weights (step 2) and counts a step's
+work for the readers (``ctx["work"]``).  ``--trace 0`` reports the cell's
+end-to-end metrics, ``--trace 1`` its per-layer metrics, read by
+``bench/metrics/<metric>.py`` from host spans over the window, a profiler
+trace of its last seconds and the program's counters at the window's
+edges; a reader that declares ``NEEDS = ("program",)`` also gets the
+program's own spans, with its tracer on for that run only.
 """
 from __future__ import annotations
 
@@ -43,7 +49,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from bench import loadgen, model_ref, spans as spans_mod, stats, tracefile
+from bench import loadgen, spans as spans_mod, stats, tracefile
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -91,15 +97,35 @@ def cell_metrics(bench: dict, cell: str):
     return e2e, layer
 
 
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, directory: Path = BENCH / "metrics"):
     """The reader module of ``metric``: ``<directory>/<base>.py``, where a
     suffix after the first ``.`` names a variant of the same reader."""
     base = metric.split(".", 1)[0]
-    path = directory / f"{base}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{base}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(directory / f"{base}.py", f"bench_metric_{base}")
+
+
+def architecture(cfgdoc: dict, directory: Path = BENCH):
+    """The configuration's architecture module: ``<directory>/<name>.py``
+    for ``"reference": "<name>"``, ``model_ref`` where it names none.  It
+    draws the weights, judges ``correct``, lists the control's types and
+    counts a step's work (see ``bench/model_ref.py``)."""
+    name = cfgdoc.get("reference", "model_ref")
+    return load_module(directory / f"{name}.py", f"bench_arch_{name}")
+
+
+def check_controls(control: Sequence[str], arch) -> None:
+    bad = [q for q in control if q not in arch.QUANTS]
+    if bad:
+        raise SystemExit(f"bench: no control type {bad[0]!r}; the "
+                         f"configuration's reference has "
+                         f"{','.join(arch.QUANTS)}")
 
 
 def check_device(chips: int):
@@ -154,20 +180,51 @@ class CompileCounter:
 
 # ----------------------------------------------------------- the model ----
 
+def model_config(base, fields: dict):
+    """The registry's ``ModelConfig`` with the configuration's ``model``
+    fields set.  A dict sets the fields of a nested dataclass (``moe``,
+    ``ssm``, ``xlstm``) over the registry's own; a key that no dataclass
+    has, or a dict for a field the registry leaves ``None``, is an error."""
+    known = {f.name for f in dataclasses.fields(base)}
+    out = {}
+    for k, v in fields.items():
+        if k not in known:
+            raise KeyError(f"configuration key model.{k}: ModelConfig has "
+                           f"no field {k!r}")
+        if isinstance(v, dict):
+            cur = getattr(base, k)
+            if not dataclasses.is_dataclass(cur):
+                raise ValueError(f"configuration key model.{k}: a group is "
+                                 f"given, but {base.name!r} has {k}={cur!r}")
+            sub = {f.name for f in dataclasses.fields(cur)}
+            for kk in v:
+                if kk not in sub:
+                    raise KeyError(f"configuration key model.{k}.{kk}: "
+                                   f"{type(cur).__name__} has no field "
+                                   f"{kk!r}")
+            v = dataclasses.replace(cur, **v)
+        out[k] = v
+    return dataclasses.replace(base, **out)
+
+
 def build_cluster(cfgdoc: dict, params, seed: int):
     from repro.configs import get_config
     from repro.models.model import Model
     from repro.serving.disagg import DisaggregatedCluster
-    base = get_config(cfgdoc["registry"])
-    fields = {f.name for f in dataclasses.fields(base)}
-    cfg = dataclasses.replace(base, **{k: v for k, v in cfgdoc["model"].items()
-                                       if k in fields})
+    cfg = model_config(get_config(cfgdoc["registry"]), cfgdoc["model"])
     dep = cfgdoc["deployment"]
     return DisaggregatedCluster(
         Model(cfg), params, num_decode=dep["num_decode"],
         slots_per_worker=dep["slots_per_worker"], max_len=dep["max_len"],
         prefill_cache_entries=dep["prefill_cache_entries"],
         max_prefill_batch=dep["max_prefill_batch"], seed=seed)
+
+
+def program_counters(cluster) -> Dict[str, float]:
+    """The values of the cluster's ``MetricsRegistry`` counters, by name."""
+    from repro.core.metrics import Counter
+    return {name: c.value for name, c in cluster.metrics._metrics.items()
+            if isinstance(c, Counter)}
 
 
 def widths(max_batch: int) -> List[int]:
@@ -468,13 +525,14 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
              seconds: float, trace: bool, t_start: float, devs,
              control: Sequence[str] = (),
              fault=None, drain_s: float = DRAIN_S,
-             metrics_dir: Path = BENCH / "metrics") -> dict:
+             bench_dir: Path = BENCH) -> dict:
     import jax
-    from repro.serving.engine import PrefillStats
 
     m = cfgdoc["model"]
+    arch = architecture(cfgdoc, bench_dir)
+    check_controls(control, arch)
     counter = CompileCounter()
-    params = jax.block_until_ready(model_ref.init_params(m, seed))
+    params = jax.block_until_ready(arch.init_params(m, seed))
     cluster = build_cluster(cfgdoc, params, seed)
     if fault is not None:
         fault(cluster)
@@ -488,6 +546,10 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
         f"(persistent-cache hits {counter.hits}, compile "
         f"{counter.compile_s!r} s)")
     e2e, layer = cell_metrics(bench, cell["name"])
+    readers = {met["name"]: reader(met["name"], bench_dir / "metrics")
+               for met in layer} if trace else {}
+    if any("program" in getattr(r, "NEEDS", ()) for r in readers.values()):
+        cluster.tracer.enable()
 
     kernels = None
     if trace:
@@ -506,22 +568,27 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
     w1 = w0 + float(seconds)
     tw = TraceWindow(max(w0, w1 - TRACE_S), w1) if trace else None
     occupancy: List[tuple] = []
-    pf_stats: Dict[str, PrefillStats] = {}
+    edges: Dict[str, tuple] = {}
+
+    def counters():
+        # prefill counters and the program's registry counters
+        return (dataclasses.replace(cluster.prefill.stats),
+                program_counters(cluster))
 
     def at_window_edges(now_fn):
-        # prefill counters and occupancy are read at the window's edges
+        # counters are read at the window's edges, occupancy inside it
         orig = drv.tick
 
         def tick():
             now = now_fn()
-            if "start" not in pf_stats and now >= w0:
-                pf_stats["start"] = dataclasses.replace(cluster.prefill.stats)
+            if "start" not in edges and now >= w0:
+                edges["start"] = counters()
             if now < w1:
                 if now >= w0:
                     occupancy.append(tuple(d.active_count
                                            for d in cluster.decoders))
-            elif "end" not in pf_stats:
-                pf_stats["end"] = dataclasses.replace(cluster.prefill.stats)
+            elif "end" not in edges:
+                edges["end"] = counters()
             return orig()
         drv.tick = tick
 
@@ -537,8 +604,8 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
     built1 = counter.snapshot()
     counter.close()
     compiles_in_window = built1[0] - built0[0]
-    pf_stats.setdefault("start", dataclasses.replace(cluster.prefill.stats))
-    pf_stats.setdefault("end", dataclasses.replace(cluster.prefill.stats))
+    edges.setdefault("start", counters())
+    edges.setdefault("end", counters())
     deadline = w1 + drain_s
     tracks = drv.tracks
     if mix["loop"] == "open":
@@ -554,14 +621,17 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
     if trace:
         ctx = {"spans": sp, "window": (w0, w1),
                "traced": tuple(tw.t) if tw and tw.t else None,
-               "prefill": (pf_stats["start"], pf_stats["end"]),
+               "prefill": (edges["start"][0], edges["end"][0]),
+               "counters": (edges["start"][1], edges["end"][1]),
                "occupancy": occupancy,
                "slots": sum(d.num_slots for d in cluster.decoders),
-               "model": m, "device_kind": dinfo["kind"], "trace": tr_data,
-               "tracks": tracks, "decode_kernels": kernels}
+               "model": m, "work": arch, "device_kind": dinfo["kind"],
+               "trace": tr_data, "tracks": tracks, "decode_kernels": kernels}
+        if cluster.tracer.enabled:
+            ctx["program"] = spans_mod.ProgramSpans(cluster.tracer.spans, t0)
         for met in layer:
             base, _, variant = met["name"].partition(".")
-            v = reader(met["name"], metrics_dir).read(ctx, variant or None)
+            v = readers[met["name"]].read(ctx, variant or None)
             if v is not None:
                 result["metrics"][met["name"]] = {"value": v,
                                                   "unit": met["unit"]}
@@ -586,7 +656,7 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
     checks: dict = {}
     if seqs:
         t_pad, p_pad = ref_sizes(mix)
-        g = model_ref.served_gaps(params, m, seqs, t_pad, p_pad, control)
+        g = arch.served_gaps(params, m, seqs, t_pad, p_pad, control)
         # each control takes the program's place: its gap is the one judged
         if control:
             checks["program_max_logit_gap"] = {"value": g["served"],
@@ -615,11 +685,7 @@ def run_cell(bench: dict, cell: dict, cfgdoc: dict, mix: dict, *, seed: int,
 # ----------------------------------------------------------------- cli ----
 
 def quant_list(text: str) -> tuple:
-    qs = tuple(q for q in text.split(",") if q)
-    for q in qs:
-        if q not in model_ref.QUANTS:
-            raise argparse.ArgumentTypeError(f"no control type {q!r}")
-    return qs
+    return tuple(q for q in text.split(",") if q)
 
 
 def parse_args(argv):
@@ -631,8 +697,8 @@ def parse_args(argv):
     p.add_argument("--control", default="", type=quant_list,
                    help="the control: judge the reference with weights "
                         "rounded to this type in the program's place; a "
-                        "comma list judges each (" + ",".join(model_ref.QUANTS)
-                        + ")")
+                        "comma list judges each (the types in QUANTS of the "
+                        "configuration's architecture module)")
     return p.parse_args(argv)
 
 
@@ -661,8 +727,7 @@ def main(argv, t_start: float, root: Path = ROOT) -> int:
     res = run_cell(bench, cell, cfgdoc, mix, seed=args.seed,
                    seconds=args.seconds, trace=bool(args.trace),
                    t_start=t_start, devs=devs,
-                   control=args.control,
-                   metrics_dir=root / "bench" / "metrics")
+                   control=args.control, bench_dir=root / "bench")
     for k, v in res["checks"].items():
         log(f"check {k}: {v['value']!r} limit {v['limit']}")
     print(json.dumps(res), flush=True)

@@ -21,6 +21,11 @@ window holds only the first hundred or so, so there the set is stratified
 per block (``SESSION_BLOCK`` sessions, ``OUTPUT_BLOCK`` output lengths)
 and each block is permuted on its own: every stretch of a few blocks holds
 the same work whatever the seed.
+A mix may fix that order with ``plan_seed``: sizes, gaps and their order
+then come from it and not from the run's seed, which still draws every
+token (and the weights).  A closed loop needs it where a tail is judged:
+there the order decides which prompts meet in one tick's prompt passes,
+and so the tail itself.
 All lengths lie on a ``grid``-token grid, which bounds the prompt shapes
 the server has to compile.
 """
@@ -59,6 +64,12 @@ def load_mix(name: str, directory: Path = TRAFFIC_DIR) -> dict:
 def rng_for(seed: int, stream: int) -> np.random.Generator:
     """Independent generator per stream of one seed (any non-negative int)."""
     return np.random.default_rng([int(seed), int(stream)])
+
+
+def plan_rng(mix: dict, seed: int) -> np.random.Generator:
+    """The generator that orders a mix's sizes and gaps: the mix's
+    ``plan_seed`` where it has one, else the run's seed."""
+    return rng_for(mix.get("plan_seed", seed), 3)
 
 
 def _exp_quantiles(n: int, mean: float) -> np.ndarray:
@@ -147,7 +158,7 @@ def _session_plan(mix: dict, n: int, perm: np.random.Generator):
 
 def _open_sessions(mix: dict, seed: int, vocab: int, horizon: float,
                    rate: float) -> List[Req]:
-    perm = rng_for(seed, 3)
+    perm = plan_rng(mix, seed)
     mean_turns = float(np.mean(mix["turns"]))
     s_rate = rate / mean_turns
     n = int(math.ceil(s_rate * horizon * 1.2)) + 4
@@ -186,7 +197,7 @@ class ClosedSessions:
 
     def __init__(self, mix: dict, seed: int, vocab: int, n_clients: int,
                  per_client: int = 64):
-        perm = rng_for(seed, 3)
+        perm = plan_rng(mix, seed)
         self.mat = _Sessions(mix, seed, vocab)
         self.n_clients = n_clients
         k = len(mix["system_prompt_tokens"])
@@ -222,7 +233,7 @@ class ClosedSessions:
 
 def _open_unshared(mix: dict, seed: int, vocab: int, horizon: float,
                    rate: float) -> List[Req]:
-    perm = rng_for(seed, 3)
+    perm = plan_rng(mix, seed)
     n = int(math.ceil(rate * horizon * 1.2)) + 4
     dues = np.cumsum(perm.permutation(_exp_quantiles(n, 1.0 / rate)))
     lens = perm.permutation(_lognormal_quantiles(n, mix["prompt"],

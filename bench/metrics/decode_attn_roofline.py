@@ -16,14 +16,17 @@ no such event (the kernel taken off the path, or a CPU run) gives no
 value.
 
 The least time for that work is the larger of its operations over the
-bf16 peak and its bytes (each live row's K and V over its positions, its
-query and output, every layer) over HBM bandwidth (``bench/flops.py``,
-``bench/peaks.py``).  Value: least time over kernel time, in %.  It reads
-the same work whichever kernel implements it."""
+bf16 peak and its bytes over HBM bandwidth (``bench/peaks.py``), both
+counted by the configuration's architecture module (``ctx["work"]``; for
+the dense module, ``bench/flops.py``: each live row's K and V over its
+positions, its query and output, every layer).  Value: least time over
+kernel time, in %.  It reads the same work whichever kernel implements
+it."""
 import re
 import sys
 
-from bench import flops, tracefile
+from bench import tracefile
+from bench.flops import roofline_seconds
 from bench.metrics_common import decode_steps
 from bench.peaks import peaks
 
@@ -44,8 +47,8 @@ def read(ctx, variant=None):
     st = decode_steps(ctx, *traced)
     if not st:
         return None
-    pk, m = peaks(ctx["device_kind"]), ctx["model"]
-    least = sum(flops.roofline_seconds(flops.attention_flops(m, lens),
-                                       flops.attention_bytes(m, lens), pk)
+    pk, m, work = peaks(ctx["device_kind"]), ctx["model"], ctx["work"]
+    least = sum(roofline_seconds(work.attention_flops(m, lens),
+                                 work.attention_bytes(m, lens), pk)
                 for _, _, lens in st)
     return 100.0 * least / kernel_s

@@ -3,11 +3,12 @@ peak.
 
 Reads the benchmark's ``decode_step`` spans (as ``decode_step_ms``) with
 the live rows' cached lengths taken before each step.  Operations come
-from the shapes (``bench/flops.py``): 2 x the weights each token multiplies
-through x live rows, plus attention's 4 x positions x heads x head_dim x
-layers.  Value: operations over span time over the bf16 peak of the
-``device_kind`` (``bench/peaks.py``), in %."""
-from bench import flops
+from the shapes, counted by the configuration's architecture module
+(``ctx["work"].decode_step_flops``; for the dense module, ``bench/flops.py``:
+2 x the weights each token multiplies through x live rows, plus
+attention's 4 x positions x heads x head_dim x layers).  Value: operations
+over span time over the bf16 peak of the ``device_kind``
+(``bench/peaks.py``), in %."""
 from bench.metrics_common import decode_steps
 from bench.peaks import peaks
 
@@ -17,6 +18,7 @@ def read(ctx, variant=None):
     if not st:
         return None
     pk = peaks(ctx["device_kind"])
-    work = sum(flops.decode_step_flops(ctx["model"], lens) for _, _, lens in st)
+    count = ctx["work"].decode_step_flops
+    work = sum(count(ctx["model"], lens) for _, _, lens in st)
     span = sum(e - s for s, e, _ in st)
     return 100.0 * work / span / pk["bf16_flops"]

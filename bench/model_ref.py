@@ -1,17 +1,28 @@
-"""Seeded weights in the served layout, and the plain float32 reference.
+"""The dense architecture module: seeded weights in the served layout, the
+plain float32 reference, and the work of a decode step.
+
+A configuration names its architecture module with ``"reference":
+"<name>"`` (``bench/<name>.py``); one that names none, as the dense
+configurations do, is judged by this one.  Every such module exports
+``QUANTS``, ``init_params(m, seed)``, ``served_gaps(params, m, seqs,
+t_pad, p_pad, controls)``, ``decode_step_flops(m, lengths)``,
+``attention_flops(m, lengths)`` and ``attention_bytes(m, lengths)``; a
+module of another architecture may build on the pieces here
+(``attention``, ``forward_logits(..., stack=...)``, ``served_gaps(...,
+stack=...)``).
 
 The benchmark makes the weights itself, from the seed, on the device, in
 one jitted call and in the type they are served in (bfloat16).  The tree
 has the layout the served model reads: ``embed`` (V, d), ``unembed``
-(d, V), ``final_norm.scale`` and ``stack.p0`` with one leading axis over
-the layers.
+(d, V), ``final_norm.scale`` and ``stack.p0.{attn, mlp}`` with one leading
+axis over the layers.
 
 The reference is a decoder written out in ``jax.numpy``: RMSNorm, full
 rotary embedding, grouped-query causal attention, a SwiGLU or squared-ReLU
 MLP, an untied head.  It runs in float32 with every product at
 ``Precision.HIGHEST``, one layer at a time, so that float32 copies of the
 weights are made for one layer and the head only.  It imports nothing of
-the program under test.
+the program under test.  The work counts are ``bench/flops.py``'s.
 
 ``quant`` gives the control: the same reference with every weight rounded
 per output channel to int8 or float8 (e4m3) before use.
@@ -24,6 +35,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.flops import (attention_bytes, attention_flops,  # noqa: F401
+                         decode_step_flops)
 
 HI = jax.lax.Precision.HIGHEST
 QUANTS = ("int8", "fp8")    # the control's weight types
@@ -111,20 +125,25 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _layer(stack, x, layer, act, eps, theta, quant):
-    """One decoder layer in float32 over x: (B, T, d)."""
-    def get(a, axes=None):
-        a = jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
-        a = a.astype(jnp.float32)
-        return a if axes is None else _quantize(a, axes, quant)
+def weight(a, layer, axes=None, quant=None):
+    """Layer ``layer`` of a stacked weight, in float32; with ``axes`` (the
+    contracted axes) rounded to ``quant`` per output channel."""
+    a = jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+    a = a.astype(jnp.float32)
+    return a if axes is None else _quantize(a, axes, quant)
 
-    at, ml = stack["p0"]["attn"], stack["p0"]["mlp"]
+
+def attention(at, x, layer, eps, theta, quant):
+    """``x`` plus layer ``layer``'s grouped-query causal attention over x:
+    (B, T, d); ``at`` holds the stacked attention weights."""
     b, t, _ = x.shape
-    xn = _rms(x, get(at["norm"]["scale"]), eps)
-    q = jnp.einsum("btd,dhk->bthk", xn, get(at["wq"], (0,)), precision=HI)
-    k = jnp.einsum("btd,dhk->bthk", xn, get(at["wk"], (0,)), precision=HI)
-    v = jnp.einsum("btd,dhk->bthk", xn, get(at["wv"], (0,)), precision=HI)
+    xn = _rms(x, weight(at["norm"]["scale"], layer), eps)
+    q = jnp.einsum("btd,dhk->bthk", xn, weight(at["wq"], layer, (0,), quant),
+                   precision=HI)
+    k = jnp.einsum("btd,dhk->bthk", xn, weight(at["wk"], layer, (0,), quant),
+                   precision=HI)
+    v = jnp.einsum("btd,dhk->bthk", xn, weight(at["wv"], layer, (0,), quant),
+                   precision=HI)
     q, k = _rope(q, theta), _rope(k, theta)
     nh, nk, hd = q.shape[2], k.shape[2], q.shape[3]
     q = q.reshape(b, t, nk, nh // nk, hd)
@@ -134,17 +153,41 @@ def _layer(stack, x, layer, act, eps, theta, quant):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgts,bskh->btkgh", p, v, precision=HI)
     o = o.reshape(b, t, nh, hd)
-    x = x + jnp.einsum("bthk,hkd->btd", o, get(at["wo"], (0, 1)), precision=HI)
-    xn = _rms(x, get(ml["norm"]["scale"]), eps)
-    u = jnp.einsum("btd,df->btf", xn, get(ml["wu"], (0,)), precision=HI)
+    return x + jnp.einsum("bthk,hkd->btd", o,
+                          weight(at["wo"], layer, (0, 1), quant), precision=HI)
+
+
+def mlp(ml, x, layer, act, eps, quant):
+    """``x`` plus layer ``layer``'s SwiGLU or squared-ReLU MLP."""
+    xn = _rms(x, weight(ml["norm"]["scale"], layer), eps)
+    u = jnp.einsum("btd,df->btf", xn, weight(ml["wu"], layer, (0,), quant),
+                   precision=HI)
     if act == "swiglu":
-        g = jnp.einsum("btd,df->btf", xn, get(ml["wg"], (0,)), precision=HI)
+        g = jnp.einsum("btd,df->btf", xn, weight(ml["wg"], layer, (0,), quant),
+                       precision=HI)
         u = jax.nn.silu(g) * u
     elif act == "squared_relu":
         u = jnp.square(jax.nn.relu(u))
     else:
         raise ValueError(f"unknown activation {act!r}")
-    return x + jnp.einsum("btf,fd->btd", u, get(ml["wd"], (0,)), precision=HI)
+    return x + jnp.einsum("btf,fd->btd", u, weight(ml["wd"], layer, (0,), quant),
+                          precision=HI)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _layer(stack, x, layer, act, eps, theta, quant):
+    """One decoder layer in float32 over x: (B, T, d)."""
+    x = attention(stack["p0"]["attn"], x, layer, eps, theta, quant)
+    return mlp(stack["p0"]["mlp"], x, layer, act, eps, quant)
+
+
+def dense_stack(params, m: dict, x, quant=None):
+    """Every layer of the dense decoder over x: (B, T, d), one at a time."""
+    eps, theta = float(m["norm_eps"]), float(m["rope_theta"])
+    for layer in range(m["num_layers"]):
+        x = _layer(params["stack"], x, layer, m["activation"], eps, theta,
+                   quant)
+    return x
 
 
 @jax.jit
@@ -180,17 +223,15 @@ def _head(final_scale, unembed, x, out_pos, eps, quant):
     return jax.lax.fori_loop(0, v // c, body, out)
 
 
-def forward_logits(params, m: dict, tokens, out_pos, quant=None):
+def forward_logits(params, m: dict, tokens, out_pos, quant=None,
+                   stack=dense_stack):
     """Reference logits (B, P, V) float32 at ``out_pos`` of the
     right-padded ``tokens`` (B, T); causal, so padding never reaches a
-    position before it."""
-    eps, theta = float(m["norm_eps"]), float(m["rope_theta"])
+    position before it.  ``stack(params, m, x, quant)`` runs the layers."""
     x = _embed(params["embed"], tokens)
-    for layer in range(m["num_layers"]):
-        x = _layer(params["stack"], x, layer, m["activation"], eps, theta,
-                   quant)
+    x = stack(params, m, x, quant)
     return _head(params["final_norm"]["scale"], params["unembed"], x, out_pos,
-                 eps, quant)
+                 float(m["norm_eps"]), quant)
 
 
 @jax.jit
@@ -222,23 +263,26 @@ def pack(seqs: Sequence[Tuple[Sequence[int], Sequence[int]]], t_pad: int,
 
 
 def served_gaps(params, m: dict, seqs, t_pad: int, p_pad: int,
-                controls: Sequence[str] = (), block: int = 4) -> dict:
+                controls: Sequence[str] = (), block: int = 4,
+                stack=dense_stack) -> dict:
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over every served token of ``seqs``.  For each of
     ``controls``, also the widest gap of the tokens that the reference
     computed with weights rounded to that type puts first, at the same
-    positions.  The reference runs over ``block`` requests at a time."""
+    positions.  The reference runs over ``block`` requests at a time, its
+    layers by ``stack`` (see ``forward_logits``)."""
     out = {"served": 0.0, "tokens": 0, "control": {q: 0.0 for q in controls}}
     for i in range(0, len(seqs), block):
         toks, pos, served, valid = pack(seqs[i:i + block], t_pad, p_pad)
         toks, pos, served_d, valid_d = (jnp.asarray(a) for a in
                                         (toks, pos, served, valid))
-        ref = forward_logits(params, m, toks, pos)
+        ref = forward_logits(params, m, toks, pos, stack=stack)
         out["served"] = max(out["served"], float(jnp.max(
             _gaps(ref, served_d, valid_d))))
         out["tokens"] += int(valid.sum())
         for q in controls:
-            ctl = forward_logits(params, m, toks, pos, quant=q)
+            ctl = forward_logits(params, m, toks, pos, quant=q,
+                                 stack=stack)
             first = jnp.argmax(ctl, axis=-1).astype(jnp.int32)
             del ctl
             out["control"][q] = max(out["control"][q], float(jnp.max(

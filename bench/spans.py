@@ -11,6 +11,7 @@ Span names: ``route`` (``ControlPlane.route``), ``prefill``
 ``decode_step`` (``DecodeEngine.step``, detail: the cached lengths of the
 live rows), ``tick`` (``DisaggregatedCluster.step``) and
 ``generator_wait`` (the load generator sleeping until the next arrival).
+``ProgramSpans`` reads the program's own spans on the same clock.
 """
 from __future__ import annotations
 
@@ -59,6 +60,26 @@ class Spans:
     def within(self, name: str, start: float, end: float):
         """Spans of ``name`` that began in [start, end)."""
         return [s for s in self.spans.get(name, ()) if start <= s[0] < end]
+
+
+class ProgramSpans:
+    """The program's own spans (``cluster.tracer.spans``, on
+    ``time.monotonic()``) read on the benchmark's clock, which starts at
+    ``t0``.  ``spans`` keeps the program's list, parents by index, for
+    ``repro.core.tracing.exclusive_seconds``."""
+
+    def __init__(self, spans, t0: float):
+        self.spans = spans
+        self.t0 = t0
+
+    def within(self, name: str, start: float, end: float):
+        """(start, end, span) on the benchmark's clock of the spans of
+        ``name`` that began in [start, end)."""
+        out = []
+        for s in self.spans:
+            if s.name == name and start <= s.start - self.t0 < end:
+                out.append((s.start - self.t0, s.end - self.t0, s))
+        return out
 
 
 def live_lengths(decoder) -> List[int]:

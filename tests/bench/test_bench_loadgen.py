@@ -63,8 +63,10 @@ def test_seeds_share_the_set_of_sizes(mix):
 
 def test_closed_loop_seeds_share_the_work_of_each_block():
     """Any stretch of whole blocks holds the same sessions and output
-    lengths for every seed, in another order."""
-    mix = loadgen.load_mix("sessions-closed-half")
+    lengths for every seed, in another order (a mix without
+    ``plan_seed``)."""
+    mix = loadgen.load_mix("sessions-closed-2x")
+    assert "plan_seed" not in mix
     sys_len = mix["system_prompt_tokens"]
     work = []
     for seed in (5, BIG_SEED):
@@ -76,6 +78,33 @@ def test_closed_loop_seeds_share_the_work_of_each_block():
         work.append((sessions, outs, g.sys_idx[:blocks].tolist()))
     assert work[0][:2] == work[1][:2]
     assert work[0][2] != work[1][2]
+
+
+def _closed_plan(mix, seed, n=8):
+    g = loadgen.ClosedSessions(mix, seed, 1000, n)
+    reqs = [g.next_request(c % n) for c in range(6 * n)]
+    return ([(r.rid, len(r.tokens), r.out_len, r.session, r.turn)
+             for r in reqs], [r.tokens for r in reqs])
+
+
+def test_plan_seed_fixes_the_order_and_the_seed_draws_the_tokens():
+    """With ``plan_seed`` every seed sends the same sizes in the same
+    order, closed loop and open; the run's seed still draws the tokens."""
+    mix = loadgen.load_mix("sessions-closed-half")
+    assert "plan_seed" in mix
+    plan_a, tok_a = _closed_plan(mix, 5)
+    plan_b, tok_b = _closed_plan(mix, BIG_SEED)
+    assert plan_a == plan_b
+    assert tok_a != tok_b
+    free = {k: v for k, v in mix.items() if k != "plan_seed"}
+    assert _closed_plan(free, 5)[0] != _closed_plan(free, BIG_SEED)[0]
+    for base in (TINY_SESSIONS, TINY_UNSHARED):
+        fixed = dict(base, plan_seed=1)
+        s1 = loadgen.open_schedule(fixed, 5, 512, 20.0)
+        s2 = loadgen.open_schedule(fixed, BIG_SEED, 512, 20.0)
+        assert [(r.rid, len(r.tokens), r.out_len, r.due) for r in s1] == \
+            [(r.rid, len(r.tokens), r.out_len, r.due) for r in s2]
+        assert [r.tokens for r in s1] != [r.tokens for r in s2]
 
 
 def test_sessions_reuse_prefixes():

@@ -8,7 +8,7 @@ import pytest
 
 from benchtest_common import ROOT, harness
 
-from bench import flops, loadgen, peaks, stats, tracefile
+from bench import flops, loadgen, model_ref, peaks, stats, tracefile
 
 FIXTURE = ROOT / "bench" / "fixtures" / "trace_small.json"
 
@@ -108,7 +108,7 @@ def test_roofline_reader_on_the_recorded_trace():
             return [(1.0, 1.5, [100, 50]), (2.0, 2.5, []), (9.0, 9.5, [10])]
 
     ctx = {"trace": tr, "traced": (0.0, 5.0), "spans": Spans(), "model": m,
-           "device_kind": "TPU v5 lite",
+           "work": model_ref, "device_kind": "TPU v5 lite",
            "decode_kernels": {"module": "jit_decode", "ops": ["custom-call.7"]}}
     pk = peaks.peaks("TPU v5 lite")
     least = sum(flops.roofline_seconds(flops.attention_flops(m, lens),
@@ -122,6 +122,39 @@ def test_roofline_reader_on_the_recorded_trace():
                  {"module": "jit_decode", "ops": []}, None):
         assert harness.reader("decode_attn_roofline").read(
             dict(ctx, decode_kernels=kern), None) is None
+
+
+class _Steps:
+    """``decode_step`` spans with the live rows' lengths."""
+    def within(self, name, a, b):
+        assert name == "decode_step"
+        return [s for s in [(1.0, 1.5, [100, 50]), (2.0, 2.5, []),
+                            (3.0, 3.25, [1156, 7, 640]), (9.0, 9.5, [10])]
+                if a <= s[0] < b]
+
+
+@pytest.mark.parametrize("config", ["phi4-mini-3.8b", "minitron-4b"])
+def test_work_readers_count_through_the_dense_module_as_before(config):
+    """Through ``ctx["work"]`` (the dense module, which the committed
+    configurations resolve to), the step share and the roofline read what
+    ``bench/flops.py`` gave them before, to the last bit."""
+    m = harness.load_json(ROOT / "bench" / "configs" / f"{config}.json")[
+        "model"]
+    work = harness.architecture({"model": m})
+    ctx = {"trace": _fixture(), "traced": (0.0, 5.0), "window": (0.0, 5.0),
+           "spans": _Steps(), "model": m, "work": work,
+           "device_kind": "TPU v5 lite",
+           "decode_kernels": {"module": "jit_decode", "ops": ["custom-call.7"]}}
+    pk = peaks.peaks("TPU v5 lite")
+    st = [s for s in _Steps().within("decode_step", 0.0, 5.0) if s[2]]
+    mfu = 100.0 * sum(flops.decode_step_flops(m, lens) for _, _, lens in st) \
+        / sum(e - s for s, e, _ in st) / pk["bf16_flops"]
+    least = sum(flops.roofline_seconds(flops.attention_flops(m, lens),
+                                       flops.attention_bytes(m, lens), pk)
+                for _, _, lens in st)
+    assert harness.reader("decode_step_mfu").read(ctx, None) == mfu
+    assert harness.reader("decode_attn_roofline").read(ctx, None) == \
+        100.0 * least / 2000e-9
 
 
 # The decode step (two layers at phi4-mini's widths) as compiled for a
